@@ -149,6 +149,15 @@ REGISTRY: Dict[str, HardwareModel] = {
 # The roofline target for the multi-pod dry-run (per the task spec).
 PRODUCTION_TARGET = TPU_V5E
 
+# ``jax.Device.device_kind`` (as the TPU runtime reports it) of each chip
+# this repo has a descriptor for.
+DEVICE_KINDS: Dict[str, str] = {
+    "TPU v4": "tpu_v4",
+    "TPU v5 lite": "tpu_v5e",
+    "TPU v5": "tpu_v5p",
+    "TPU v6 lite": "tpu_v6e",
+}
+
 
 def get(name: str) -> HardwareModel:
     try:
@@ -156,4 +165,17 @@ def get(name: str) -> HardwareModel:
     except KeyError:
         raise KeyError(
             f"unknown hardware model {name!r}; known: {sorted(REGISTRY)}"
+        ) from None
+
+
+def for_device_kind(kind: str) -> HardwareModel:
+    """The descriptor of a running chip, by its ``device_kind``. An unknown
+    kind is an error: plans and timings for one chip must never be read as
+    another's."""
+    try:
+        return REGISTRY[DEVICE_KINDS[kind]]
+    except KeyError:
+        raise KeyError(
+            f"no hardware descriptor for device kind {kind!r}; known: "
+            f"{sorted(DEVICE_KINDS)}"
         ) from None

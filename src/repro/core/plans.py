@@ -219,7 +219,8 @@ def _fit_tile(tile: TileShape, kernel: str, problem: Mapping[str, int],
         min(d, m) for d, m in zip(tile.dims, constraints.max_dims)
     ))
     budget = hw.vmem_bytes * constraints.vmem_fraction
-    if spec.vmem_bytes(fitted, problem, dtype) > budget:
+    if (spec.vmem_bytes(fitted, problem, dtype) > budget
+            or not constraints.block_legal(fitted, hw, dtype)):
         return None
     return fitted
 

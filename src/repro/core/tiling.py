@@ -71,6 +71,10 @@ class TileConstraints:
     sublane_dim: Optional[int] = None
     # Fraction of VMEM the tile working set may use (double-buffering => 0.5).
     vmem_fraction: float = 0.5
+    # Dims the kernel cannot pad: the tile must divide the problem extent
+    # and be a whole multiple of the dim's alignment (or the whole extent),
+    # which is what a Pallas TPU BlockSpec accepts.
+    exact_dims: Tuple[int, ...] = ()
 
     def alignment(self, hw: HardwareModel, dtype: str, dim_index: int) -> int:
         if dim_index == self.lane_dim:
@@ -80,6 +84,14 @@ class TileConstraints:
         if dim_index in self.mxu_dims:
             return hw.mxu_dim
         return 1
+
+    def block_legal(self, tile: "TileShape", hw: HardwareModel,
+                    dtype: str) -> bool:
+        """Whether every ``exact_dims`` entry of ``tile`` divides its extent
+        and is aligned (or spans the whole extent)."""
+        return all(block_fits(tile[i], self.max_dims[i],
+                              self.alignment(hw, dtype, i))
+                   for i in self.exact_dims)
 
 
 def _candidates_for_dim(limit: int, align: int) -> List[int]:
@@ -110,7 +122,9 @@ def enumerate_tiles(
 
     ``vmem_bytes_fn(tile) -> int`` gives the per-step VMEM working set.
     Candidates violating the VMEM budget are discarded, mirroring the paper's
-    "threads per block <= 512" legality filter.
+    "threads per block <= 512" legality filter, and so are candidates whose
+    ``exact_dims`` the kernel could not run (see
+    :meth:`TileConstraints.block_legal`).
     """
     axes: List[List[int]] = []
     for i in range(constraints.rank):
@@ -125,7 +139,8 @@ def enumerate_tiles(
     tiles: List[TileShape] = []
     for dims in itertools.product(*axes):
         t = TileShape(tuple(dims))
-        if vmem_bytes_fn(t) <= budget:
+        if (vmem_bytes_fn(t) <= budget
+                and constraints.block_legal(t, hw, dtype)):
             tiles.append(t)
     # Prefer larger tiles first (fewer grid steps) as the tie-break ordering.
     tiles.sort(key=lambda t: (-t.size, t.dims))
@@ -138,6 +153,13 @@ def round_up(x: int, multiple: int) -> int:
 
 def cdiv(a: int, b: int) -> int:
     return (a + b - 1) // b
+
+
+def block_fits(block: int, extent: int, align: int) -> bool:
+    """Whether a Pallas TPU block of ``block`` can tile ``extent`` with no
+    padding: it divides the extent and is a multiple of ``align`` or the
+    whole extent (the chip's compiler refuses any other block shape)."""
+    return extent % block == 0 and (block == extent or block % align == 0)
 
 
 def padded_extent(extent: int, tile: int) -> int:

@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
+from repro.core.tiling import block_fits
 from repro.kernels.flash_attention.ref import fit_bkv  # noqa: F401 (re-export)
 
 NEG_INF = -2.0e30
@@ -46,6 +46,17 @@ NEG_INF = -2.0e30
 # Grouped-query rows are padded up to one fp32 sublane so the [rep, bkv]
 # logits block is a legal VPU/MXU operand even for MQA (rep == 1).
 MIN_GROUP_ROWS = 8
+
+# TPU vector lanes: the minor dim of a block must be a multiple of this or
+# the whole array dim.
+LANES = 128
+
+
+def split_legal(bkv: int, s: int) -> bool:
+    """Whether the Pallas kernel can run a ``bkv`` split of an ``s``-slot
+    cache on a TPU: the split divides the cache, and the ``kv_pos`` block
+    ``[1, bkv]`` is lane-aligned or spans the whole cache."""
+    return block_fits(bkv, s, LANES)
 
 
 def _decode_kernel(
@@ -119,7 +130,8 @@ def flash_decode(
 
     ``pos`` is the query's absolute position (traced scalar is fine);
     ``kv_pos`` [S] maps cache slots to absolute positions (ring caches),
-    default linear. ``bkv`` must divide the cache length S.
+    default linear. ``bkv`` must divide the cache length S; on a TPU it must
+    also satisfy :func:`split_legal` (interpret mode does not check it).
     """
     b, hq, d = q.shape
     hkv, s = k.shape[1], k.shape[2]
@@ -163,7 +175,7 @@ def flash_decode(
             pltpu.VMEM((rep_p, 128), jnp.float32),   # running denom
             pltpu.VMEM((rep_p, d), jnp.float32),     # output accumulator
         ],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

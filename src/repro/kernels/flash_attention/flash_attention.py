@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
 
 NEG_INF = -2.0e30
 
@@ -138,7 +137,7 @@ def flash_attention(
             pltpu.VMEM((bq, 128), jnp.float32),   # running denom
             pltpu.VMEM((bq, d), jnp.float32),     # output accumulator
         ],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

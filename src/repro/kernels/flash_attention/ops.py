@@ -146,10 +146,13 @@ def _group_rows(problem: Mapping[str, int]) -> int:
 
 
 def _decode_constraints(problem: Mapping[str, int]) -> TileConstraints:
-    # bkv is the lane dim of the [rep, bkv] logits block and the N dim of
-    # the q @ k^T MXU op; it wants lane (128) multiples.
+    # bkv is the lane dim of the [rep, bkv] logits block and of the kernel's
+    # [1, bkv] kv_pos block, and the N dim of the q @ k^T MXU op: it must
+    # divide the cache and be a lane (128) multiple or the whole cache
+    # (decode.split_legal) — the chip's compiler refuses anything else.
     return TileConstraints(
         rank=1, max_dims=(problem["skv"],), mxu_dims=(0,), lane_dim=0,
+        exact_dims=(0,),
     )
 
 

@@ -3,6 +3,13 @@
 Problem dims: {"m", "k", "n"}. Tile rank 3 = (bm, bk, bn). The VMEM working
 set per grid step is a(bm,bk) + b(bk,bn) + out(bm,bn) + acc f32(bm,bn) — the
 TPU analogue of the paper's threads-per-block legality bound.
+
+The model stack tunes one cell per FF block: the up projections run the
+tile as (bm, bk, bn) over [m, k] @ [k, n] and the down projection runs it
+transposed, (bm, bn, bk) over [m, n] @ [n, k] (``models.transformer``). So
+``bk`` and ``bn`` must divide the weight dims exactly (weights are never
+padded; activation rows are, so ``bm`` need not divide ``m``), and the
+working set is that of the larger orientation.
 """
 from __future__ import annotations
 
@@ -27,14 +34,15 @@ def _constraints(problem: Mapping[str, int]) -> TileConstraints:
     m, k, n = problem["m"], problem["k"], problem["n"]
     return TileConstraints(
         rank=3, max_dims=(m, k, n),
-        mxu_dims=(0, 1, 2), lane_dim=2, sublane_dim=0,
+        mxu_dims=(0, 1, 2), lane_dim=2, sublane_dim=0, exact_dims=(1, 2),
     )
 
 
 def _vmem_bytes(tile: TileShape, problem: Mapping[str, int], dtype: str) -> float:
     bm, bk, bn = tile
     b = dtype_bytes(dtype)
-    return bm * bk * b + bk * bn * b + bm * bn * b + bm * bn * 4  # + f32 acc
+    # a + b + out + f32 acc, for the larger of the two orientations.
+    return bk * bn * b + bm * (bk + bn) * b + bm * max(bk, bn) * 4
 
 
 def _workload(tile: TileShape, problem: Mapping[str, int], dtype: str) -> TileWorkload:
@@ -61,14 +69,24 @@ def _n_tiles(tile: TileShape, problem: Mapping[str, int]) -> int:
     )
 
 
+def _divisor_block(extent: int, cap: int) -> int:
+    """The largest lane-aligned block <= ``cap`` dividing ``extent`` (the
+    whole extent when none does)."""
+    for block in range(cap - cap % 128, 0, -128):
+        if extent % block == 0:
+            return block
+    return extent
+
+
 def _default_tile(problem: Mapping[str, int], dtype: str) -> TileShape:
     m, k, n = problem["m"], problem["k"], problem["n"]
     # Wide-minor-first heuristic (the 32x4 principle, MXU-scaled): large bn
     # for lane contiguity, bm sized to keep the f32 accumulator modest, bk
-    # grown to amortize the accumulator over more MXU work.
-    bn = min(512, n)
+    # grown to amortize the accumulator over more MXU work. bk and bn divide
+    # the weight dims (see the module docstring).
+    bn = n if n <= 512 else _divisor_block(n, 512)
     bm = min(256, m)
-    bk = min(512, k)
+    bk = k if k <= 512 else _divisor_block(k, 512)
     return TileShape((bm, bk, bn))
 
 

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
-
 
 def _rglru_kernel(a_ref, x_ref, h0_ref, y_ref, hout_ref, h_ref, *, bt: int, n_t: int):
     it = pl.program_id(2)
@@ -83,7 +81,7 @@ def rglru_scan(
             jax.ShapeDtypeStruct((b, f), x.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((1, bf), jnp.float32)],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
-
 
 def _ssd_kernel(la_ref, x_ref, b_ref, c_ref, h0_ref, y_ref, hout_ref, h_ref,
                 *, q: int, n_c: int):
@@ -103,7 +101,7 @@ def ssd_scan(
             jax.ShapeDtypeStruct((b, h, n, p), dtx.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

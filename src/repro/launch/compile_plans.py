@@ -33,6 +33,7 @@ from repro import configs, kernels
 from repro.configs import shapes as shape_families
 from repro.core import HARDWARE_REGISTRY, Autotuner
 from repro.core.plans import PLAN_SCHEMA_VERSION, PlanJob, compile_plan
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.specs import cell_problems, kernel_problems
 
 # Kernels modelled only for one hardware family: everything defaults to the
@@ -199,6 +200,7 @@ def main(argv: Optional[Sequence[str]] = None) -> str:
                          "to the analytic model per cell otherwise")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     if args.all_archs:
         args.archs = configs.list_archs()
     buckets = sorted({int(x) for x in args.serve_buckets.split(",") if x})

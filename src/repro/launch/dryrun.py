@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this driver builds abstract params/optimizer/batch specs
@@ -13,8 +10,12 @@ unless --force.
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2-1.5b --shape train_4k
     PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod-only|--single-pod-only]
+
+The dry-run needs 512 host devices: run it as its own process (``main``
+sets ``XLA_FLAGS`` before JAX initialises its backend).
 """
 import argparse
+import os
 import dataclasses
 import json
 import time
@@ -352,6 +353,9 @@ def plan_hit_report(plans, arch: str, shape_name: str,
 
 
 def main():
+    # Must precede backend initialisation (the first device query or
+    # compile), which nothing at import time triggers.
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -8,15 +8,16 @@ kernel's jitted Pallas op on synthetic operands with warmup, which the
 autotuner then prefers over analytic scores (``SweepEntry.measured_s``
 outranks ``cost.total_s``).
 
-Gating: measurement requires the running jax backend to be a TPU *and* the
-target hardware descriptor to be TPU-family (we cannot wall-clock a GTX260
-descriptor on a TPU). Anything else returns None and the caller falls back
-to the analytic model — the compile never fails for lack of hardware.
+Gating: measurement requires the running jax backend to be a TPU whose
+``device_kind`` maps to the very descriptor being measured (a v5e cannot
+time a v6e cell, let alone a GTX260 one). On host backends it returns None
+and the caller keeps the analytic model; a TPU backend that fails to
+initialise raises.
 
 ``make_cell_timer`` wraps the same machinery as the *always-available*
 timing path shared by plan compilation and the serving engines' shadow
-execution (``repro.serve.refine``): wall-clock when hardware is present,
-the analytic cost-model score otherwise.
+execution (``repro.serve.refine``): wall-clock when the running chip is the
+descriptor's, the analytic cost-model score otherwise.
 """
 from __future__ import annotations
 
@@ -70,6 +71,7 @@ def _flash_call(problem: Mapping[str, int], dtype: str):
 def _flash_decode_call(problem: Mapping[str, int], dtype: str):
     import jax.numpy as jnp
 
+    from repro.kernels.flash_attention.decode import split_legal
     from repro.kernels.flash_attention.ops import attend_decode
 
     rng = np.random.default_rng(0)
@@ -83,8 +85,8 @@ def _flash_decode_call(problem: Mapping[str, int], dtype: str):
     pos = jnp.asarray(skv - 1, jnp.int32)       # steady state: full cache
 
     def call(tile):
-        if skv % int(tile[0]):
-            # The Pallas kernel cannot run a non-dividing split; score it
+        if not split_legal(int(tile[0]), skv):
+            # The Pallas kernel cannot run this split on the chip; score it
             # infeasible so the sweep never certifies a tile the serve
             # path would then reject.
             return None
@@ -148,14 +150,16 @@ _BUILDERS = {
 
 
 def hardware_available(hw: HardwareModel) -> bool:
-    """True when the running backend can execute kernels for ``hw``."""
+    """True when the running chip is ``hw``: a TPU backend whose
+    ``device_kind`` maps to that descriptor. Host backends are never any
+    descriptor; an unknown TPU kind raises (``hardware.for_device_kind``)."""
     import jax
 
-    try:
-        backend = jax.default_backend()
-    except RuntimeError:
+    from repro.core.hardware import for_device_kind
+
+    if jax.default_backend() != "tpu":
         return False
-    return backend == "tpu" and hw.family == "tpu"
+    return for_device_kind(jax.devices()[0].device_kind).name == hw.name
 
 
 def make_measure_fn(
@@ -168,8 +172,9 @@ def make_measure_fn(
 ) -> Optional[MeasureFn]:
     """A tile -> wall-clock-seconds hook for one cell, or None.
 
-    None (analytic fallback) when no real TPU backend is present, the target
-    descriptor is not TPU-family, or the kernel has no operand builder. A
+    None (analytic path) when the running backend is not the chip ``hw``
+    describes (see :func:`hardware_available`), or the kernel has no
+    operand builder. A
     builder call may itself return None for a candidate its kernel cannot
     legally run (e.g. a non-dividing decode split); that candidate measures
     +inf and never wins the sweep.
@@ -212,8 +217,8 @@ def make_cell_timer(
 ) -> MeasureFn:
     """The shared timing path for plan compilation AND shadow execution.
 
-    Wall-clock via :func:`make_measure_fn` when the running backend can
-    execute kernels for ``hw``; the analytic cost-model score otherwise.
+    Wall-clock via :func:`make_measure_fn` when the running chip is
+    ``hw``; the analytic cost-model score otherwise.
     Unlike ``make_measure_fn`` (which returns None off-hardware so the
     compiler can distinguish measured from analytic artifacts), this always
     returns a callable — shadow steps must produce *a* comparable number on

@@ -1,10 +1,25 @@
-"""Serving launcher: batched request demo on the reduced config.
+"""Serving launcher: random-weight requests through the serving engine.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --requests 6
 
+The reduced (smoke) config by default; ``--full`` serves the architecture at
+its published widths, ``--dtype bfloat16`` in bf16 (the weights are random,
+drawn from a fixed seed in one jitted call). On one TPU v5e:
+
+    PYTHONPATH=src python -m repro.launch.serve --full --dtype bfloat16 \
+        --paged --pack-prefill --scheduler bucket --bucket-policy 128,512 \
+        --max-len 1024 --prompt-len 100,500 --new-tokens 32 \
+        --tile-plans plans.json
+
+``--prompt-len lo,hi`` draws prompt lengths from [lo, hi). ``--hardware``
+defaults to the running chip's descriptor (by ``device_kind``) on a TPU and
+to the modelled production target on host backends. ``main(argv)`` can be
+called in-process; it returns the finished requests and the metrics, and
+exits nonzero when requests are left unfinished.
+
 ``--tile-plans plans.json`` resolves decode-path kernel tiles from a
 compiled AOT artifact (see ``repro.launch.compile_plans``) instead of
-tuning lazily; a corrupt/missing artifact degrades to heuristics.
+tuning lazily; an artifact that cannot be read is an error.
 
 ``--scheduler bucket`` switches admission to the shape-bucketed scheduler
 (``--bucket-policy`` sets the shape family: "64,128,512", "pow2:16:512", or
@@ -68,11 +83,14 @@ import logging
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
 from repro.core import HARDWARE_REGISTRY, PRODUCTION_TARGET
-from repro.core.plans import TilePlan
+from repro.core.hardware import for_device_kind
+from repro.core.plans import PlanError, TilePlan
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.serve import (BucketPolicy, FleetExhausted, FleetRouter,
                          ServeEngine, make_scheduler)
@@ -94,18 +112,43 @@ def build_policy(spec: str, plans, hardware_name, max_queue: int,
                               allow_overflow=allow_overflow)
 
 
-def main():
+def init_params(cfg, dtype, seed: int = 0):
+    """Random weights drawn from ``seed`` in ``dtype`` by one jitted call."""
+    return jax.jit(lambda key: api.init_params(cfg, key, dtype))(
+        jax.random.PRNGKey(seed))
+
+
+def running_hardware():
+    """The running chip's descriptor on a TPU backend; the modelled
+    production target on host backends (which model, not run, a chip)."""
+    if jax.default_backend() == "tpu":
+        return for_device_kind(jax.devices()[0].device_kind)
+    return PRODUCTION_TARGET
+
+
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b",
                     choices=configs.list_archs())
+    ap.add_argument("--full", action="store_true",
+                    help="serve the architecture at its published widths "
+                         "(default: the reduced smoke config)")
+    ap.add_argument("--dtype", default="float32",
+                    choices=("float32", "bfloat16"),
+                    help="parameter, activation and KV-cache dtype")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--new-tokens", type=int, default=12)
+    ap.add_argument("--prompt-len", default="4,12",
+                    help="prompt lengths are drawn from [lo, hi): 'lo,hi'")
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--tile-plans", default=None,
                     help="compiled TilePlan artifact (JSON)")
-    ap.add_argument("--hardware", default=PRODUCTION_TARGET.name,
-                    choices=sorted(HARDWARE_REGISTRY))
+    ap.add_argument("--hardware", default=None,
+                    choices=sorted(HARDWARE_REGISTRY),
+                    help="descriptor to resolve tiles for (default: the "
+                         "running chip on a TPU, else the production "
+                         "target)")
     ap.add_argument("--scheduler", default="fifo", choices=("fifo", "bucket"),
                     help="admission policy: naive FIFO or shape-bucketed")
     ap.add_argument("--bucket-policy", default="pow2:16:128",
@@ -172,10 +215,11 @@ def main():
                     help="write a request-lifecycle / plan-audit trace here "
                          "(.jsonl for JSONL, else Chrome/Perfetto JSON; "
                          "inspect with python -m repro.launch.trace_report)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
+    enable_compile_cache()
     # The fleet router's cost model (and autoscale candidate pricing)
     # scores default tiles straight from the kernel registry; engines only
     # register lazily on their first plan resolution, which is too late
@@ -183,9 +227,18 @@ def main():
     from repro import kernels
 
     kernels.register_all()
-    cfg = configs.get_smoke(args.arch)
-    params = api.init_params(cfg, jax.random.PRNGKey(0))
-    plans = TilePlan.load_or_none(args.tile_plans)
+    cfg = (configs.get_arch(args.arch) if args.full
+           else configs.get_smoke(args.arch))
+    dtype = jnp.dtype(args.dtype)
+    hardware = args.hardware or running_hardware().name
+    lo, hi = (int(x) for x in args.prompt_len.split(","))
+    params = init_params(cfg, dtype)
+    plans = None
+    if args.tile_plans:
+        try:
+            plans = TilePlan.load(args.tile_plans)
+        except PlanError as exc:
+            raise SystemExit(f"unusable --tile-plans artifact: {exc}")
 
     refiner = None
     if args.refine:
@@ -210,14 +263,14 @@ def main():
         # and engines share one bucketing; single engine: its own cells.
         policy = build_policy(
             args.bucket_policy, plans,
-            None if fleet_names else args.hardware, args.max_queue,
+            None if fleet_names else hardware, args.max_queue,
             allow_overflow=(args.chunk_prefill or args.pack_prefill
                             or args.paged))
 
     def make_engine(hw_name: str, instance: str = None) -> ServeEngine:
         return ServeEngine(
             cfg, params, max_len=args.max_len, slots=args.slots,
-            plans=plans, hardware=HARDWARE_REGISTRY[hw_name],
+            dtype=dtype, plans=plans, hardware=HARDWARE_REGISTRY[hw_name],
             scheduler=make_scheduler(args.scheduler, policy),
             chunk_prefill=args.chunk_prefill,
             step_token_budget=args.step_token_budget,
@@ -260,13 +313,13 @@ def main():
         raise SystemExit("--autoscale requires --fleet (the candidates come "
                          "from its hardware list)")
     else:
-        engine = make_engine(args.hardware)
+        engine = make_engine(hardware)
 
     rng = np.random.default_rng(0)
     t0 = time.perf_counter()
     rejected = 0
     for i in range(args.requests):
-        prompt = rng.integers(2, cfg.vocab_size, size=rng.integers(4, 12))
+        prompt = rng.integers(2, cfg.vocab_size, size=rng.integers(lo, hi))
         if router is not None:
             ok = router.route(prompt, max_new_tokens=args.new_tokens)
         else:
@@ -277,11 +330,12 @@ def main():
         try:
             done_by = router.run_until_done()
         except FleetExhausted as exc:
-            # Surface exhaustion loudly (a partial result set must never
-            # read as a complete run) but still report what DID finish.
-            print(f"WARNING: {exc}")
-            done_by = {name: list(eng._finished)
-                       for name, eng in router.engines.items()}
+            # A partial result set must never read as a complete run:
+            # report what did finish, then fail.
+            for name, eng in sorted(router.engines.items()):
+                for r in eng._finished:
+                    print(f"req {r.rid}@{name}: {r.out_tokens}")
+            raise SystemExit(f"fleet exhausted: {exc}")
         done = [r for rs in done_by.values() for r in rs]
         for name, rs in sorted(done_by.items()):
             for r in rs:
@@ -302,6 +356,10 @@ def main():
         done = engine.run_until_done()
         for r in done:
             print(f"req {r.rid}: {r.out_tokens}")
+        if engine.in_flight() or engine.scheduler.pending():
+            raise SystemExit(
+                f"engine stopped with {engine.in_flight()} request(s) in "
+                f"flight and {engine.scheduler.pending()} queued")
         metrics = engine.metrics.as_dict()
 
     dt = time.perf_counter() - t0
@@ -355,6 +413,8 @@ def main():
             print(eng.metrics.render())
     else:
         print(engine.metrics.render())
+    return {"requests": done, "rejected": rejected, "metrics": metrics,
+            "wall_s": dt}
 
 
 if __name__ == "__main__":

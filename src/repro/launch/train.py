@@ -1,11 +1,10 @@
 """Training launcher.
 
-CPU demo (default): reduced config, real training loop with checkpoints:
+Reduced config by default, real training loop with checkpoints:
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b --steps 50
 
-Production flags mirror the dry-run: ``--mesh single|multi`` builds the
-16x16 / 2x16x16 mesh (on a real TPU slice the same code path runs the full
-config; on this CPU container use --smoke).
+``--full`` trains the architecture at its published widths. The trainer
+runs on one device: this launcher builds no mesh.
 """
 from __future__ import annotations
 
@@ -17,6 +16,7 @@ import jax.numpy as jnp
 
 from repro import configs
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import adamw
 from repro.train.trainer import Trainer, TrainerConfig
 
@@ -47,6 +47,7 @@ def main():
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
+    enable_compile_cache()
     cfg = (configs.get_smoke(args.arch) if args.smoke
            else configs.get_arch(args.arch))
     data_cfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq_len,

@@ -35,6 +35,7 @@ from repro.kernels.flash_attention.chunked import (
 )
 from repro.kernels.flash_attention.decode import (
     fit_bkv, flash_decode, flash_decode_ref, paged_gather, paged_write,
+    split_legal,
 )
 from repro.kernels.flash_attention.flash_attention import flash_attention
 from repro.kernels.flash_attention.ref import flash_attention_ref
@@ -48,30 +49,33 @@ NEG_INF = -2.0e30
 # attention call site — cheap, and exactly when a plan tile goes unused.
 # ---------------------------------------------------------------------------
 
-_tile_event_sink: Optional[Callable[[Dict[str, Any]], None]] = None
+_tile_event_sinks: Tuple[Callable[[Dict[str, Any]], None], ...] = ()
 
 
 @contextlib.contextmanager
 def capture_tile_events(sink: Callable[[Dict[str, Any]], None]):
     """Route tile-dispatch events emitted under this context to ``sink``.
 
-    Events are dicts: ``kernel`` (flash_attention | flash_decode), ``phase``
-    (prefill | decode), ``impl`` (the lowering actually used), ``tile`` (the
-    requested dims), ``effective`` (the parameter the lowering really used)
-    and ``fallback`` (True when the plan's tile did not legally apply).
+    Events are dicts: ``kernel`` (flash_attention | flash_decode |
+    chunked_prefill | packed_prefill | matmul), ``phase`` (prefill |
+    decode), ``impl`` (the lowering actually used), ``tile`` (the requested
+    dims), ``effective`` (the parameter the lowering really used) and
+    ``fallback`` (True when the plan's tile did not legally apply).
+    Captures nest: every enclosing sink sees each event, so a caller can
+    watch the programs an engine traces under its own capture.
     """
-    global _tile_event_sink
-    prev = _tile_event_sink
-    _tile_event_sink = sink
+    global _tile_event_sinks
+    prev = _tile_event_sinks
+    _tile_event_sinks = prev + (sink,)
     try:
         yield
     finally:
-        _tile_event_sink = prev
+        _tile_event_sinks = prev
 
 
-def _emit_tile_event(**event) -> None:
-    if _tile_event_sink is not None:
-        _tile_event_sink(dict(event))
+def emit_tile_event(**event) -> None:
+    for sink in _tile_event_sinks:
+        sink(dict(event))
 
 
 def attn_defs(cfg: ArchConfig) -> Dict[str, ParamDef]:
@@ -211,7 +215,7 @@ def attn_forward(
         out = flash_attention(q, k, v, tile=t or (512, 512),
                               interpret=flags.pallas_interpret(), **kwargs)
         if tile is not None:
-            _emit_tile_event(kernel="flash_attention", phase="prefill",
+            emit_tile_event(kernel="flash_attention", phase="prefill",
                              impl="pallas", tile=tuple(tile),
                              effective=t, fallback=False)
     else:
@@ -224,7 +228,7 @@ def attn_forward(
             effective = fit_bkv(chunk, s)
             fallback = (effective != chunk
                         or (flags.pallas_enabled() and not divides))
-            _emit_tile_event(kernel="flash_attention", phase="prefill",
+            emit_tile_event(kernel="flash_attention", phase="prefill",
                              impl="reference", tile=tuple(tile),
                              effective=effective, fallback=fallback)
         else:
@@ -289,7 +293,7 @@ def attn_prefill_chunk(
         if tile is not None:
             requested = min(int(tile[-1]), skv)
             effective = fit_bkv(requested, skv)
-            _emit_tile_event(kernel="chunked_prefill", phase="prefill",
+            emit_tile_event(kernel="chunked_prefill", phase="prefill",
                              impl="reference", tile=tuple(tile),
                              effective=effective,
                              fallback=effective != requested)
@@ -318,7 +322,7 @@ def attn_prefill_chunk(
         if tile is not None:
             requested = min(int(tile[-1]), skv)
             effective = fit_bkv(requested, skv)
-            _emit_tile_event(kernel="chunked_prefill", phase="prefill",
+            emit_tile_event(kernel="chunked_prefill", phase="prefill",
                              impl="reference", tile=tuple(tile),
                              effective=effective,
                              fallback=effective != requested)
@@ -354,14 +358,14 @@ def attn_prefill_chunk(
                                   interpret=flags.pallas_interpret(),
                                   **kwargs)
             if tile is not None:
-                _emit_tile_event(kernel="chunked_prefill", phase="prefill",
+                emit_tile_event(kernel="chunked_prefill", phase="prefill",
                                  impl="pallas", tile=tuple(tile),
                                  effective=t, fallback=False)
         else:
             if tile is not None:
                 requested = min(int(tile[1]), skv)
                 effective = fit_bkv(requested, skv)
-                _emit_tile_event(
+                emit_tile_event(
                     kernel="chunked_prefill", phase="prefill",
                     impl="reference", tile=tuple(tile), effective=effective,
                     fallback=(effective != requested
@@ -475,7 +479,7 @@ def attn_prefill_packed(
     if tile is not None:
         requested = min(int(tile[-1]), skv)
         effective = fit_bkv(requested, skv)
-        _emit_tile_event(kernel="packed_prefill", phase="prefill",
+        emit_tile_event(kernel="packed_prefill", phase="prefill",
                          impl="reference", tile=tuple(tile),
                          effective=effective,
                          fallback=effective != requested)
@@ -602,8 +606,8 @@ def attn_decode(
 
     ``tile`` is the plan-resolved decode tile (``TileShape`` or tuple whose
     last dim is ``bkv``, the split-KV chunk). ``impl``: "auto" picks the
-    Pallas flash-decode kernel on TPU backends when the tile legally divides
-    the cache length, the chunked flash-decode reference when a tile is
+    Pallas flash-decode kernel on TPU backends when the kernel can run the
+    split over the cache (``decode.split_legal``), the chunked flash-decode reference when a tile is
     present elsewhere (``bkv`` sets the online-softmax KV split — a resolved
     plan changes the lowered computation on every backend), and the dense
     masked attend when no tile resolved (the pre-plan lowering). "dense" /
@@ -663,12 +667,12 @@ def attn_decode(
 
     bkv = int(tile[-1]) if tile is not None else None
     clamped = min(bkv, max_len) if bkv is not None else None
-    divides = clamped is not None and max_len % clamped == 0
+    legal = clamped is not None and split_legal(clamped, max_len)
     auto = impl == "auto"
     if auto:
         if bkv is None:
             impl = "dense"
-        elif flags.pallas_enabled() and divides:
+        elif flags.pallas_enabled() and legal:
             impl = "pallas"
         else:
             impl = "flash_ref"
@@ -679,8 +683,11 @@ def attn_decode(
         elif impl == "dense":
             fallback = True                 # forced dense ignores the tile
         else:                               # flash_ref: ran, but at the
-            fallback = effective != clamped  # snapped (not the plan's) split
-        _emit_tile_event(
+            # snapped (not the plan's) split, or in place of a kernel the
+            # split could not run.
+            fallback = (effective != clamped
+                        or (flags.pallas_enabled() and not legal))
+        emit_tile_event(
             kernel="flash_decode", phase="decode", impl=impl,
             tile=tuple(tile), effective=effective, fallback=fallback,
         )

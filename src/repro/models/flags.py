@@ -67,14 +67,12 @@ def pallas_enabled() -> bool:
     model stack. True only on a real TPU backend — or under interpret-mode
     Pallas (see :func:`pallas_interpret`): the kernels cannot lower to host
     HLO, so CPU/GPU backends keep the reference lowerings (tiles still
-    parameterize those — e.g. the flash reference's KV chunk)."""
+    parameterize those — e.g. the flash reference's KV chunk). A backend
+    that fails to initialise raises: it is never read as "no TPU"."""
     import jax
     if pallas_interpret():
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def remat_policy():

@@ -27,7 +27,10 @@ class ParamDef:
 
 
 def init_tree(defs: Dict[str, Any], key: jax.Array, dtype) -> Dict[str, Any]:
-    """Materialize a nested dict of ParamDefs into arrays (deterministic)."""
+    """Materialize a nested dict of ParamDefs into arrays (deterministic).
+
+    Every array is drawn in ``dtype`` itself, so a bf16 tree never passes
+    through a float32 copy (``jax.jit`` it to build the tree on device)."""
     flat, treedef = jax.tree.flatten(
         defs, is_leaf=lambda x: isinstance(x, ParamDef)
     )
@@ -39,11 +42,11 @@ def init_tree(defs: Dict[str, Any], key: jax.Array, dtype) -> Dict[str, Any]:
         elif d.init == "ones":
             arr = jnp.ones(d.shape, dtype)
         elif d.init == "normal":
-            arr = (jax.random.normal(k, d.shape) * d.scale).astype(dtype)
+            arr = jax.random.normal(k, d.shape, dtype) * d.scale
         elif d.init == "fan_in":
             fan_in = d.shape[0] if len(d.shape) > 1 else d.shape[0]
             std = d.scale / math.sqrt(max(fan_in, 1))
-            arr = (jax.random.normal(k, d.shape) * std).astype(dtype)
+            arr = jax.random.normal(k, d.shape, dtype) * std
         else:
             raise ValueError(f"unknown init {d.init}")
         out.append(arr)

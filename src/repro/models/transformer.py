@@ -24,6 +24,7 @@ from repro.models import moe as moe_mod
 from repro.models import rglru as rglru_mod
 from repro.models import ssm as ssm_mod
 from repro.models import flags
+from repro.core.tiling import block_fits, round_up
 from repro.models.context import DistContext
 from repro.models.layers import (
     ParamDef, act_fn, axes_tree, init_tree, layer_norm, rms_norm, softcap,
@@ -246,32 +247,53 @@ def _mixer_packed(p, cfg: ArchConfig, spec: LayerSpec, x, positions, caches,
     return jnp.concatenate(outs, axis=1), tuple(news)
 
 
-def _tile_fits(tile, m: int, k: int, n: int) -> bool:
-    """True when the (clamped) tile divides the GEMM — pallas_call legality."""
-    return all(dim % min(t, dim) == 0
-               for t, dim in zip(tile, (m, k, n)))
+def _ff_blocks(tile, m: int, d: int, f: int):
+    """Pallas blocks for the FF GEMM pair from the plan's matmul tile
+    ``(bm, bk, bn)``: the up projections ``[m, d] @ [d, f]`` run
+    ``(bm, bk, bn)`` and the down projection ``[m, f] @ [f, d]`` runs the
+    transpose ``(bm, bn, bk)``. Returns ``(up, down, padded m)`` — rows are
+    padded to a whole number of ``bm`` blocks — or None when ``bk``/``bn``
+    cannot block the weights (they must divide them and be lane-aligned or
+    whole, as the matmul cell's legality requires)."""
+    bk, bn = min(tile[1], d), min(tile[2], f)
+    if not (block_fits(bk, d, 128) and block_fits(bn, f, 128)):
+        return None
+    bm = min(round_up(min(tile[0], m), 8), m)
+    return (bm, bk, bn), (bm, bn, bk), round_up(m, bm)
 
 
-def _dense_ff(p, cfg: ArchConfig, x, tile=None):
+def _dense_ff(p, cfg: ArchConfig, x, tile=None, phase: str = "prefill"):
     """SwiGLU FF. ``tile`` is the plan-resolved matmul tile (bm, bk, bn);
-    on TPU backends the projection GEMMs run through the tiled Pallas matmul
-    kernel with it (inference paths), elsewhere the tile is advisory and the
-    einsum lowering is kept (Pallas TPU kernels cannot lower to host HLO)."""
+    on TPU backends (and under interpret-mode Pallas) the three GEMMs run
+    through the tiled Pallas matmul kernel with the blocks of
+    :func:`_ff_blocks` (inference paths), elsewhere the einsum lowering is
+    kept (Pallas TPU kernels cannot lower to host HLO). Like the attention
+    sites, a call with a tile emits a tile event (``kernel="matmul"``), so
+    a tile the kernel could not run is counted as a fallback."""
     act = act_fn(cfg.act)
     b, s, d = x.shape
     f = p["w1"].shape[1]
-    if (tile is not None and flags.pallas_enabled()
-            and _tile_fits(tile, b * s, d, f)
-            and _tile_fits(tile, b * s, f, d)):
-        from repro.kernels.matmul.ops import mm
+    if tile is not None:
+        blocks = _ff_blocks(tile, b * s, d, f)
+        pallas = flags.pallas_enabled() and blocks is not None
+        attn_mod.emit_tile_event(
+            kernel="matmul", phase=phase,
+            impl="pallas" if pallas else "reference", tile=tuple(tile),
+            effective=blocks[0] if blocks else None,
+            fallback=flags.pallas_enabled() and not pallas)
+        if pallas:
+            from repro.kernels.matmul.ops import mm
 
-        xf = x.reshape(b * s, d)
-        t = tuple(tile)
-        interp = flags.pallas_interpret()
-        h = act(mm(xf, p["w1"].astype(x.dtype), tile=t, interpret=interp))
-        h = h * mm(xf, p["w3"].astype(x.dtype), tile=t, interpret=interp)
-        return mm(h, p["w2"].astype(x.dtype), tile=t,
-                  interpret=interp).reshape(b, s, -1)
+            up, down, mp = blocks
+            xf = x.reshape(b * s, d)
+            xf = jnp.pad(xf, ((0, mp - b * s), (0, 0)))
+            interp = flags.pallas_interpret()
+            h = act(mm(xf, p["w1"].astype(x.dtype), tile=up,
+                       interpret=interp))
+            h = h * mm(xf, p["w3"].astype(x.dtype), tile=up,
+                       interpret=interp)
+            y = mm(h, p["w2"].astype(x.dtype), tile=down, interpret=interp)
+            return y[:b * s].reshape(b, s, d)
     h = act(jnp.einsum("bsd,df->bsf", x, p["w1"].astype(x.dtype)))
     h = h * jnp.einsum("bsd,df->bsf", x, p["w3"].astype(x.dtype))
     return jnp.einsum("bsf,fd->bsd", h, p["w2"].astype(x.dtype))
@@ -287,6 +309,7 @@ def layer_forward(
     returned new_cache matches."""
     aux = jnp.zeros((), jnp.float32)
     ff_tile = (tiles or {}).get("matmul")
+    phase = "decode" if decode else "prefill"
     h = _apply_norm(p, cfg, x, "norm1")
     mix, new_cache = _mixer(p, cfg, spec, h, positions, cache, decode, ctx,
                             tiles, chunk_start=chunk_start,
@@ -295,14 +318,14 @@ def layer_forward(
         mix = _apply_norm(p, cfg, mix, "post1")
 
     if cfg.parallel_block and spec.ff is not None:
-        ff = _dense_ff(p["ff"], cfg, h, tile=ff_tile)
+        ff = _dense_ff(p["ff"], cfg, h, tile=ff_tile, phase=phase)
         x = x + mix + ff
     else:
         x = x + mix
         if spec.ff is not None:
             h2 = _apply_norm(p, cfg, x, "norm2")
             if spec.ff == "dense":
-                ff = _dense_ff(p["ff"], cfg, h2, tile=ff_tile)
+                ff = _dense_ff(p["ff"], cfg, h2, tile=ff_tile, phase=phase)
             else:
                 ff, aux = moe_mod.moe_forward(p["moe"], cfg, h2, ctx)
             if cfg.post_norms:

@@ -609,6 +609,32 @@ def test_measure_fn_gated_off_without_tpu():
     assert a.tile == b.tile and a.score_s == b.score_s
 
 
+@pytest.mark.parametrize("kind,hw_name,expect", [
+    ("TPU v5 lite", "tpu_v5e", True),
+    ("TPU v5 lite", "tpu_v6e", False),
+    ("TPU v6 lite", "tpu_v6e", True),
+    ("TPU v99", "tpu_v5e", KeyError),
+])
+def test_measure_gate_follows_device_kind(kind, hw_name, expect,
+                                          monkeypatch):
+    """On a TPU backend only the descriptor the running chip's device_kind
+    maps to is timed; an unknown kind is an error, never a default."""
+    from types import SimpleNamespace
+
+    from repro.core import HARDWARE_REGISTRY
+    from repro.launch.measure import hardware_available
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices",
+                        lambda: [SimpleNamespace(device_kind=kind)])
+    hw = HARDWARE_REGISTRY[hw_name]
+    if expect is KeyError:
+        with pytest.raises(KeyError):
+            hardware_available(hw)
+    else:
+        assert hardware_available(hw) is expect
+
+
 def test_measure_fn_drives_sweep_selection():
     """A measure_fn's wall-clock scores outrank the analytic model in
     compile_entry (the real-TPU path, exercised with a fake measurer)."""

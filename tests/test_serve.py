@@ -156,3 +156,33 @@ def test_engine_threads_resolved_tiles_into_prefill():
     # The prefill trace saw the plan's bkv (clamped to seq 16) as its chunk.
     expect = min(exact.tile[1], 16)
     assert expect in seen
+
+
+def test_launcher_exits_nonzero_on_fleet_exhausted(tmp_path, monkeypatch):
+    """A fleet that cannot drain is a failed run, not a warning."""
+    from repro.launch import compile_cache, serve
+    from repro.serve import FleetExhausted, FleetRouter
+
+    monkeypatch.setenv(compile_cache.ENV, str(tmp_path / "cache"))
+
+    def exhausted(self, max_steps=1000):
+        raise FleetExhausted(max_steps, {"tpu_v5e": {"in_flight": 1,
+                                                     "queued": 1}})
+
+    monkeypatch.setattr(FleetRouter, "run_until_done", exhausted)
+    with pytest.raises(SystemExit) as exc:
+        serve.main(["--fleet", "tpu_v5e", "--scheduler", "bucket",
+                    "--requests", "2", "--new-tokens", "2"])
+    assert exc.value.code not in (0, None)
+
+
+def test_launcher_refuses_unusable_plan_artifact(tmp_path, monkeypatch):
+    """An artifact that cannot be read is an error, never heuristics."""
+    from repro.launch import compile_cache, serve
+
+    monkeypatch.setenv(compile_cache.ENV, str(tmp_path / "cache"))
+    bad = tmp_path / "plans.json"
+    bad.write_text('{"schema_version": 99, "entries": []}')
+    with pytest.raises(SystemExit) as exc:
+        serve.main(["--tile-plans", str(bad), "--requests", "1"])
+    assert exc.value.code not in (0, None)
