@@ -5,6 +5,8 @@ plan-decision audit instants and scheduler queue events against an injected
 clock (virtual-clock bench runs trace deterministically);
 ``repro.obs.export`` emits the Chrome-trace/Perfetto JSON and JSONL
 artifacts the ``python -m repro.launch.trace_report`` CLI consumes.
+``repro.obs.trace.region`` marks the engine's steps and launches as
+``serve.*`` regions of a ``jax.profiler`` trace, on the device's clock.
 """
 from repro.obs.export import (
     load_trace,

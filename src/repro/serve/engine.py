@@ -73,7 +73,12 @@ engine records the full causal timeline on its injected clock — request
 lifecycle (submit → admit/reject → chunks with pack membership and queue
 age → first token → decode → finish), per-step spans, and plan-resolution
 audit instants. With no tracer (the default) every site short-circuits on
-``self._trace is None``: zero allocations, zero calls.
+``self._trace is None``: zero allocations, zero calls. Independently of
+the tracer, each step, admission, prefill launch and decode launch is a
+``serve.*`` region of a ``jax.profiler`` trace (``repro.obs.trace.region``),
+and every program the engine jits has a stable name (``serve_decode``,
+``serve_chunk_paged``, ...), so a device trace shows which engine program
+each device operation ran in.
 """
 from __future__ import annotations
 
@@ -94,6 +99,7 @@ from repro.core.plans import (PLAN_SCHEMA_VERSION, PlanResolution,
 from repro.core.tiling import TileShape, cdiv
 from repro.models import api
 from repro.models import attention as attn_mod
+from repro.obs.trace import region
 from repro.serve.metrics import ServeMetrics
 from repro.serve.pool import PagedKVPool
 from repro.serve.scheduler import FifoScheduler
@@ -132,6 +138,12 @@ class _ChunkJob:
     @property
     def remaining(self) -> int:
         return len(self.prompt) - self.done
+
+    def unpadded(self, take: int) -> int:
+        """Prompt tokens among the next ``take`` this job prefills, the
+        scheduler's left pads not counted."""
+        pad = len(self.prompt) - len(self.req.prompt)
+        return take - max(0, min(pad, self.done + take) - self.done)
 
 
 class ServeEngine:
@@ -287,14 +299,7 @@ class ServeEngine:
                 dtype=dtype, prefix_sharing=prefix_sharing,
                 metrics=self.metrics, trace=self._trace)
 
-        self._decode = jax.jit(
-            lambda p, tok, st: api.decode_step(p, cfg, tok, st,
-                                               tiles=self.tiles or None)
-        )
-        self._decode_paged = jax.jit(
-            lambda p, tok, st, arrays, table: api.decode_step_paged(
-                p, cfg, tok, st, arrays, table, tiles=self.tiles or None)
-        )
+        self._build_decode_programs()
         # Prefill programs are built per admitted length so each shape
         # family gets its own exactly-resolved tiles (see _prefill_fn).
         self._prefill_fns: Dict[int, Any] = {}
@@ -488,19 +493,26 @@ class ServeEngine:
         self._plan_schema = None
         if plans is not None:
             self._resolve_tiles(plans)
-        cfg = self.cfg
-        self._decode = jax.jit(
-            lambda p, tok, st: api.decode_step(p, cfg, tok, st,
-                                               tiles=self.tiles or None)
-        )
-        self._decode_paged = jax.jit(
-            lambda p, tok, st, arrays, table: api.decode_step_paged(
-                p, cfg, tok, st, arrays, table, tiles=self.tiles or None)
-        )
+        self._build_decode_programs()
         if self._trace is not None:
             refined_from = (plans.meta.get("refined_from")
                             if plans is not None else None)
             self._trace.plan_swap(self._plan_schema, refined_from)
+
+    def _build_decode_programs(self) -> None:
+        """(Re)build the decode programs, which read ``self.tiles`` when
+        they trace."""
+        cfg = self.cfg
+
+        def serve_decode(p, tok, st):
+            return api.decode_step(p, cfg, tok, st, tiles=self.tiles or None)
+
+        def serve_decode_paged(p, tok, st, arrays, table):
+            return api.decode_step_paged(p, cfg, tok, st, arrays, table,
+                                         tiles=self.tiles or None)
+
+        self._decode = jax.jit(serve_decode)
+        self._decode_paged = jax.jit(serve_decode_paged)
 
     def _prefill_fn(self, length: int):
         """The jitted prefill program for one admitted prompt length.
@@ -537,11 +549,13 @@ class ServeEngine:
                 for kernel in kernel_problems(self.cfg, 1, length, "prefill")
             }
         cfg, max_len, dtype = self.cfg, self.max_len, self.dtype
-        fn = jax.jit(
-            lambda p, batch: api.prefill(
-                p, cfg, batch, max_len=max_len, dtype=dtype,
-                ring_local=bool(cfg.attn_window), tiles=tiles or None)
-        )
+
+        def serve_prefill(p, batch):
+            return api.prefill(p, cfg, batch, max_len=max_len, dtype=dtype,
+                               ring_local=bool(cfg.attn_window),
+                               tiles=tiles or None)
+
+        fn = jax.jit(serve_prefill)
         self._prefill_fns[length] = fn
         self._prefill_sources[length] = sources
         if self._trace is not None:
@@ -694,17 +708,16 @@ class ServeEngine:
             return fn
         _, tiles, _ = self._chunk_plan(admit_len)
         cfg = self.cfg
-        if self.paged:
-            fn = jax.jit(
-                lambda p, toks, st, arrays, table: api.prefill_chunk_paged(
-                    p, cfg, toks, st, start, arrays, table,
-                    tiles=tiles or None)
-            )
-        else:
-            fn = jax.jit(
-                lambda p, toks, st: api.prefill_chunk(
-                    p, cfg, toks, st, start, tiles=tiles or None)
-            )
+
+        def serve_chunk_paged(p, toks, st, arrays, table):
+            return api.prefill_chunk_paged(p, cfg, toks, st, start, arrays,
+                                           table, tiles=tiles or None)
+
+        def serve_chunk(p, toks, st):
+            return api.prefill_chunk(p, cfg, toks, st, start,
+                                     tiles=tiles or None)
+
+        fn = jax.jit(serve_chunk_paged if self.paged else serve_chunk)
         self._chunk_fns[key] = fn
         return fn
 
@@ -774,17 +787,16 @@ class ServeEngine:
             self._pack_tile_events.pop(oldest, None)
         _, tiles, _ = self._pack_plan()
         cfg = self.cfg
-        if self.paged:
-            fn = jax.jit(
-                lambda p, toks, sts, arrays, tbls: api.prefill_packed_paged(
-                    p, cfg, toks, sts, layout, arrays, tbls,
-                    tiles=tiles or None)
-            )
-        else:
-            fn = jax.jit(
-                lambda p, toks, sts: api.prefill_packed(
-                    p, cfg, toks, sts, layout, tiles=tiles or None)
-            )
+
+        def serve_pack_paged(p, toks, sts, arrays, tbls):
+            return api.prefill_packed_paged(p, cfg, toks, sts, layout, arrays,
+                                            tbls, tiles=tiles or None)
+
+        def serve_pack(p, toks, sts):
+            return api.prefill_packed(p, cfg, toks, sts, layout,
+                                      tiles=tiles or None)
+
+        fn = jax.jit(serve_pack_paged if self.paged else serve_pack)
         self._pack_fns[layout] = fn
         return fn
 
@@ -828,42 +840,44 @@ class ServeEngine:
         returns the pack's total token count."""
         jobs = [job for job, _ in picks]
         layout = tuple((job.done, take) for job, take in picks)
-        t0 = self._clock() if self._trace is not None else None
-        for job in jobs:
-            self._ensure_state(job)
-        toks = jnp.asarray(np.concatenate([
-            job.prompt[start:start + take]
-            for job, (start, take) in zip(jobs, layout)
-        ])[None])
-        fn = self._pack_fn(layout)
-        states = tuple(job.state for job in jobs)
-        events = self._pack_tile_events.get(layout)
-        if self.paged:
-            for job, (start, take) in zip(jobs, layout):
-                self.pool.prepare_span(job.req.rid, start, take)
-            tables = tuple(self.pool.device_table(job.req.rid)
-                           for job in jobs)
-            args = (self.params, toks, states, self.pool.arrays, tables)
-            if events is None:
-                captured: List[Dict[str, Any]] = []
-                with attn_mod.capture_tile_events(captured.append):
+        with region("prefill", program="pack", segments=len(picks),
+                    tokens=sum(job.unpadded(take) for job, take in picks)):
+            t0 = self._clock() if self._trace is not None else None
+            for job in jobs:
+                self._ensure_state(job)
+            toks = jnp.asarray(np.concatenate([
+                job.prompt[start:start + take]
+                for job, (start, take) in zip(jobs, layout)
+            ])[None])
+            fn = self._pack_fn(layout)
+            states = tuple(job.state for job in jobs)
+            events = self._pack_tile_events.get(layout)
+            if self.paged:
+                for job, (start, take) in zip(jobs, layout):
+                    self.pool.prepare_span(job.req.rid, start, take)
+                tables = tuple(self.pool.device_table(job.req.rid)
+                               for job in jobs)
+                args = (self.params, toks, states, self.pool.arrays, tables)
+                if events is None:
+                    captured: List[Dict[str, Any]] = []
+                    with attn_mod.capture_tile_events(captured.append):
+                        logits, new_states, self.pool.arrays = fn(*args)
+                    events = self._dedupe_events(captured)
+                    self._pack_tile_events[layout] = events
+                else:
                     logits, new_states, self.pool.arrays = fn(*args)
+            elif events is None:
+                captured = []
+                with attn_mod.capture_tile_events(captured.append):
+                    logits, new_states = fn(self.params, toks, states)
                 events = self._dedupe_events(captured)
                 self._pack_tile_events[layout] = events
             else:
-                logits, new_states, self.pool.arrays = fn(*args)
-        elif events is None:
-            captured = []
-            with attn_mod.capture_tile_events(captured.append):
                 logits, new_states = fn(self.params, toks, states)
-            events = self._dedupe_events(captured)
-            self._pack_tile_events[layout] = events
-        else:
-            logits, new_states = fn(self.params, toks, states)
-        for i, (job, (start, take)) in enumerate(zip(jobs, layout)):
-            job.state = new_states[i]
-            self._advance_job(job, take, events, logits[i][None],
-                              packed=True, pack_n=len(jobs), lane=i, t0=t0)
+            for i, (job, (start, take)) in enumerate(zip(jobs, layout)):
+                job.state = new_states[i]
+                self._advance_job(job, take, events, logits[i][None],
+                                  packed=True, pack_n=len(jobs), lane=i, t0=t0)
         return sum(take for _, take in layout)
 
     def _is_multi_chunk(self, req: Request) -> bool:
@@ -1017,33 +1031,35 @@ class ServeEngine:
         """Advance one job by one chunk; returns the chunk's token count."""
         start = job.done
         length = min(job.chunk_len, len(job.prompt) - start)
-        t0 = self._clock() if self._trace is not None else None
-        self._ensure_state(job)
-        fn = self._chunk_fn(len(job.prompt), start)
-        toks = jnp.asarray(job.prompt[None, start:start + length])
-        key = (len(job.prompt), start)
-        events = self._chunk_tile_events.get(key)
-        if self.paged:
-            self.pool.prepare_span(job.req.rid, start, length)
-            args = (self.params, toks, job.state, self.pool.arrays,
-                    self.pool.device_table(job.req.rid))
-            if events is None:
-                captured: List[Dict[str, Any]] = []
-                with attn_mod.capture_tile_events(captured.append):
+        with region("prefill", program="chunk", segments=1,
+                    tokens=job.unpadded(length)):
+            t0 = self._clock() if self._trace is not None else None
+            self._ensure_state(job)
+            fn = self._chunk_fn(len(job.prompt), start)
+            toks = jnp.asarray(job.prompt[None, start:start + length])
+            key = (len(job.prompt), start)
+            events = self._chunk_tile_events.get(key)
+            if self.paged:
+                self.pool.prepare_span(job.req.rid, start, length)
+                args = (self.params, toks, job.state, self.pool.arrays,
+                        self.pool.device_table(job.req.rid))
+                if events is None:
+                    captured: List[Dict[str, Any]] = []
+                    with attn_mod.capture_tile_events(captured.append):
+                        logits, job.state, self.pool.arrays = fn(*args)
+                    events = self._dedupe_events(captured)
+                    self._chunk_tile_events[key] = events
+                else:
                     logits, job.state, self.pool.arrays = fn(*args)
+            elif events is None:
+                captured = []
+                with attn_mod.capture_tile_events(captured.append):
+                    logits, job.state = fn(self.params, toks, job.state)
                 events = self._dedupe_events(captured)
                 self._chunk_tile_events[key] = events
             else:
-                logits, job.state, self.pool.arrays = fn(*args)
-        elif events is None:
-            captured = []
-            with attn_mod.capture_tile_events(captured.append):
                 logits, job.state = fn(self.params, toks, job.state)
-            events = self._dedupe_events(captured)
-            self._chunk_tile_events[key] = events
-        else:
-            logits, job.state = fn(self.params, toks, job.state)
-        self._advance_job(job, length, events, logits, t0=t0)
+            self._advance_job(job, length, events, logits, t0=t0)
         return length
 
     def _finish_prefill(self, job: _ChunkJob, logits) -> None:
@@ -1170,20 +1186,22 @@ class ServeEngine:
                 self.metrics.record_plan("prefill", kernel, source)
             sub_t = (self.metrics.submit_time(req.rid)
                      if self._trace is not None else None)
-            t0 = self._clock() if self._trace is not None else None
-            batch = {"tokens": jnp.asarray(prompt[None])}
-            events = self._prefill_tile_events.get(len(prompt))
-            if events is None:
-                captured: List[Dict[str, Any]] = []
-                with attn_mod.capture_tile_events(captured.append):
+            with region("prefill", program="prefill", segments=1,
+                        tokens=len(req.prompt)):
+                t0 = self._clock() if self._trace is not None else None
+                batch = {"tokens": jnp.asarray(prompt[None])}
+                events = self._prefill_tile_events.get(len(prompt))
+                if events is None:
+                    captured: List[Dict[str, Any]] = []
+                    with attn_mod.capture_tile_events(captured.append):
+                        logits, state = prefill(self.params, batch)
+                    events = self._dedupe_events(captured)
+                    self._prefill_tile_events[len(prompt)] = events
+                else:
                     logits, state = prefill(self.params, batch)
-                events = self._dedupe_events(captured)
-                self._prefill_tile_events[len(prompt)] = events
-            else:
-                logits, state = prefill(self.params, batch)
-            for ev in events:
-                self._record_tile_event(ev)
-            tok = int(jnp.argmax(logits[0, :self.cfg.vocab_size]))
+                for ev in events:
+                    self._record_tile_event(ev)
+                tok = int(jnp.argmax(logits[0, :self.cfg.vocab_size]))
             req.out_tokens.append(tok)
             self.metrics.record_first_token(req.rid, req.bucket)
             if self._trace is not None:
@@ -1220,51 +1238,53 @@ class ServeEngine:
             active_buckets.append(req.bucket)
             if trace_rids is not None:
                 trace_rids.append(req.rid)
-            last = jnp.asarray([[req.out_tokens[-1]]], jnp.int32)
-            if self.paged:
-                # The decode program writes this token's K/V at the next
-                # cache position — make its page writable (CoW-splitting a
-                # shared one) before the launch.
-                pos = self._pos[req.rid]
-                self.pool.prepare_span(req.rid, pos, 1)
-                self._pos[req.rid] = pos + 1
-                args = (self.params, last, self._states[i],
-                        self.pool.arrays, self.pool.device_table(req.rid))
-                if self._decode_tile_events is None:
-                    captured: List[Dict[str, Any]] = []
-                    with attn_mod.capture_tile_events(captured.append):
+            with region("decode", tokens=1):
+                last = jnp.asarray([[req.out_tokens[-1]]], jnp.int32)
+                if self.paged:
+                    # The decode program writes this token's K/V at the
+                    # next cache position — make its page writable
+                    # (CoW-splitting a shared one) before the launch.
+                    pos = self._pos[req.rid]
+                    self.pool.prepare_span(req.rid, pos, 1)
+                    self._pos[req.rid] = pos + 1
+                    args = (self.params, last, self._states[i],
+                            self.pool.arrays, self.pool.device_table(req.rid))
+                    if self._decode_tile_events is None:
+                        captured: List[Dict[str, Any]] = []
+                        with attn_mod.capture_tile_events(captured.append):
+                            (logits, self._states[i],
+                             self.pool.arrays) = self._decode_paged(*args)
+                        self._decode_tile_events = self._dedupe_events(
+                            captured)
+                        for ev in self._decode_tile_events:
+                            self._record_tile_event(ev)
+                    else:
                         (logits, self._states[i],
                          self.pool.arrays) = self._decode_paged(*args)
+                elif self._decode_tile_events is None:
+                    captured = []
+                    with attn_mod.capture_tile_events(captured.append):
+                        logits, self._states[i] = self._decode(
+                            self.params, last, self._states[i])
                     self._decode_tile_events = self._dedupe_events(captured)
                     for ev in self._decode_tile_events:
                         self._record_tile_event(ev)
                 else:
-                    (logits, self._states[i],
-                     self.pool.arrays) = self._decode_paged(*args)
-            elif self._decode_tile_events is None:
-                captured = []
-                with attn_mod.capture_tile_events(captured.append):
                     logits, self._states[i] = self._decode(
                         self.params, last, self._states[i])
-                self._decode_tile_events = self._dedupe_events(captured)
-                for ev in self._decode_tile_events:
-                    self._record_tile_event(ev)
-            else:
-                logits, self._states[i] = self._decode(
-                    self.params, last, self._states[i])
-            tok = int(jnp.argmax(logits[0, :self.cfg.vocab_size]))
-            req.out_tokens.append(tok)
-            if len(req.out_tokens) >= req.max_new_tokens:
-                req.done = True
-                self._active[i] = None
-                self._states[i] = None
-                if self.paged:
-                    self.pool.release(req.rid)
-                    self._pos.pop(req.rid, None)
-                self._finished.append(req)
-                self.metrics.record_complete()
-                if self._trace is not None:
-                    self._trace.finish(req.rid, len(req.out_tokens))
+                tok = int(jnp.argmax(logits[0, :self.cfg.vocab_size]))
+                req.out_tokens.append(tok)
+                if len(req.out_tokens) >= req.max_new_tokens:
+                    req.done = True
+                    self._active[i] = None
+                    self._states[i] = None
+                    if self.paged:
+                        self.pool.release(req.rid)
+                        self._pos.pop(req.rid, None)
+                    self._finished.append(req)
+                    self.metrics.record_complete()
+                    if self._trace is not None:
+                        self._trace.finish(req.rid, len(req.out_tokens))
         self.metrics.record_decode_step(active_buckets, self._clock() - t0)
         if trace_rids is not None and n:
             self._trace.decode(t0, trace_rids)
@@ -1279,10 +1299,15 @@ class ServeEngine:
         in-flight prefill co-scheduled with the whole decode batch, the two
         together bounded by ``step_token_budget`` tokens.
         """
-        if self.chunk_prefill:
-            return self._step_chunked()
+        with region("step", step=self.steps_run):
+            if self.chunk_prefill:
+                return self._step_chunked()
+            return self._step_whole()
+
+    def _step_whole(self) -> int:
         t0 = self._clock() if self._trace is not None else 0.0
-        prefill_tokens, segments = self._admit()
+        with region("admit"):
+            prefill_tokens, segments = self._admit()
         self._record_backlog(self.scheduler.pending())
         n = self._decode_all()
         # Second admission pass: requests that FINISHED in this step's
@@ -1291,7 +1316,8 @@ class ServeEngine:
         # instead of idling one extra step per turnover. Admission-order
         # and token math are untouched; only the latency of reusing a
         # freed slot changes.
-        extra_tokens, extra_segments = self._admit()
+        with region("admit"):
+            extra_tokens, extra_segments = self._admit()
         prefill_tokens += extra_tokens
         segments = segments + extra_segments
         self.last_step_stats = {"prefill_tokens": prefill_tokens,
@@ -1306,7 +1332,8 @@ class ServeEngine:
 
     def _step_chunked(self) -> int:
         t0 = self._clock() if self._trace is not None else 0.0
-        self._admit_chunked()
+        with region("admit"):
+            self._admit_chunked()
         # Held (deferred multi-chunk) requests are still backlog.
         self._record_backlog(self.scheduler.pending() + len(self._held)
                              + len(self._pool_wait))
@@ -1326,7 +1353,8 @@ class ServeEngine:
                     prefill_tokens = self._run_chunk(picks[0][0])
                 else:
                     prefill_tokens = self._run_pack(picks)
-                self._admit_chunked()
+                with region("admit"):
+                    self._admit_chunked()
         else:
             job = self._next_chunk_job()
             if job is not None:
@@ -1337,20 +1365,19 @@ class ServeEngine:
                 # A prefill finished by that chunk may start decoding this
                 # very step if a slot is free — its first decode token
                 # rides the same mixed step.
-                self._admit_chunked()
+                with region("admit"):
+                    self._admit_chunked()
         n = self._decode_all()
-        # Second admission pass (same rationale as step()): decode just
+        # Second admission pass (same rationale as _step_whole): decode just
         # released the slots/pool pages of every request it finished, so a
         # waiting request admits THIS step — in paged mode this is also
         # what lets a pool-starved request claim freed pages without a
         # one-step bubble.
-        self._admit_chunked()
+        with region("admit"):
+            self._admit_chunked()
         if self.paged:
             self.metrics.record_pool(self.pool.used_pages,
                                      self.pool.n_pages)
-            if self._trace is not None:
-                self._trace.pool_occupancy(self.pool.used_pages,
-                                           self.pool.n_pages)
         self.last_step_stats = {"prefill_tokens": prefill_tokens,
                                 "decode_tokens": n,
                                 "packed_chunks": len(packed_rids),

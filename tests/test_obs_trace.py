@@ -17,7 +17,10 @@ The contracts under test:
   wider than the retained circular buffer, and ``FleetRouter.roll_plans``
   treats a clipped window as inconclusive (no confident keep/revert);
 * **the diff CLI** — ``repro.launch.trace_report --diff`` exits 0 on an
-  identical pair and nonzero when the candidate's p95 TTFT regresses.
+  identical pair and nonzero when the candidate's p95 TTFT regresses;
+* **device-trace regions** — under ``jax.profiler`` the engine's
+  ``serve.admit``/``serve.prefill``/``serve.decode`` regions nest in
+  ``serve.step`` with their args, and its programs are ``jit_serve_*``.
 
 Engine-driving tests are marked ``slow`` (the CI packing-conformance lane
 runs them next to the packing suite); everything else is fast-lane.
@@ -87,9 +90,10 @@ def test_ttft_span_reproduces_metrics_sample():
     span = [e for e in tr.events if e["name"] == "ttft"][0]
     assert span["ts"] == 1.0 and span["dur"] == 0.25
     assert span["args"] == {"rid": 7, "bucket": 64}
-    # No submit time -> instant only, no span (metrics recorded nothing).
+    # No submit time -> no span (metrics recorded nothing), and no
+    # first_token instant either way: the ttft span ends at that moment.
     p.first_token(8, 64, None)
-    assert len([e for e in tr.events if e["name"] == "ttft"]) == 1
+    assert [e["name"] for e in tr.events] == ["ttft"]
 
 
 def _tiny_trace(tmp_path, name="t.json"):
@@ -369,3 +373,122 @@ def test_disabled_tracing_makes_zero_tracer_calls(smoke_model, monkeypatch):
     assert eng._trace is None
     assert eng.metrics.completed > 0
     assert calls["n"] == 0, "hot path touched the tracer while disabled"
+
+
+# --------------------------------------------------------------------------
+# serve.* regions and named programs in a jax.profiler trace
+# --------------------------------------------------------------------------
+
+def _profiled_regions(cfg, params, tmp_path, packed):
+    """Drive a paged, packed engine (or, with ``packed`` false, the
+    per-request engine that prefills whole prompts at admission) under
+    ``jax.profiler.trace``; returns the engine, its prompts and the
+    trace's ``serve.*`` host events as ``(name, start_ns, end_ns,
+    stats)``."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData, ProfileOptions
+
+    from repro.serve import BucketPolicy, ServeEngine, ShapeBucketScheduler
+
+    eng = ServeEngine(
+        cfg, params, max_len=max(EDGES) + 16, slots=2,
+        scheduler=ShapeBucketScheduler(BucketPolicy(EDGES, max_queue=99)),
+        chunk_prefill=packed, pack_prefill=packed, prefill_slots=3,
+        step_token_budget=32 if packed else 0, paged=packed,
+        prefix_sharing=False)
+    # Short prompts pack several to a launch; 20-40 tokens fill the
+    # 64 bucket mostly with left pads.
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(2, 64, size=n).astype(np.int32)
+               for n in (5, 7, 30, 6, 21, 3, 40)]
+    options = ProfileOptions()
+    options.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=options):
+        for prompt in prompts:
+            eng.add_request(prompt, max_new_tokens=NEW_TOKENS)
+        for _ in range(200):
+            if not (eng.step() or eng.scheduler.pending()):
+                break
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            events += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                        dict(e.stats)) for e in line.events
+                       if e.name.startswith("serve.")]
+    return eng, prompts, sorted(events, key=lambda e: e[1])
+
+
+@pytest.mark.parametrize("packed,programs", [
+    (True, {"chunk", "pack"}), (False, {"prefill"})])
+def test_engine_regions_nest_in_steps(smoke_model, tmp_path, packed,
+                                      programs):
+    cfg, params = smoke_model
+    eng, prompts, events = _profiled_regions(cfg, params, tmp_path, packed)
+    steps = [e for e in events if e[0] == "serve.step"]
+    assert [e[3]["step"] for e in steps] == list(range(eng.steps_run))
+    inner = [e for e in events if e[0] != "serve.step"]
+    assert {e[0] for e in inner} == {"serve.admit", "serve.prefill",
+                                     "serve.decode"}
+    for name, start, end, _ in inner:
+        assert any(s[1] <= start and end <= s[2] for s in steps), name
+    prefills = [e[3] for e in events if e[0] == "serve.prefill"]
+    assert {p["program"] for p in prefills} == programs
+    for p in prefills:
+        assert (p["segments"] >= 2) == (p["program"] == "pack"), p
+    # Unpadded prompt tokens: the bucket's left pads are not counted.
+    assert sum(p["tokens"] for p in prefills) == sum(map(len, prompts))
+    decodes = [e[3] for e in events if e[0] == "serve.decode"]
+    assert all(d == {"tokens": 1} for d in decodes)
+    done = eng._finished
+    assert len(done) == len(prompts)
+    assert len(decodes) == sum(len(r.out_tokens) - 1 for r in done)
+
+
+@pytest.mark.parametrize("paged", [True, False])
+def test_engine_programs_are_named(smoke_model, paged):
+    """Every program the engine jits lowers to a module named
+    ``jit_serve_*`` (the name a device trace shows), also after a plan
+    swap rebuilds the decode programs."""
+    import re
+
+    import jax.numpy as jnp
+
+    from repro.models import api
+    from repro.serve import BucketPolicy, ServeEngine, ShapeBucketScheduler
+
+    cfg, params = smoke_model
+    eng = ServeEngine(
+        cfg, params, max_len=max(EDGES) + 16, slots=2,
+        scheduler=ShapeBucketScheduler(BucketPolicy(EDGES, max_queue=99)),
+        pack_prefill=True, prefill_slots=2, paged=paged)
+    eng.set_plans(None)
+    toks = jnp.zeros((1, 8), jnp.int32)
+    if paged:
+        state = api.make_paged_state(cfg, eng.dtype)
+        table = jnp.zeros((eng.pool.n_pt,), jnp.int32)
+        pool = (eng.pool.arrays,)
+        programs = {
+            "jit_serve_decode_paged": (eng._decode_paged, toks[:, :1], state,
+                                       *pool, table),
+            "jit_serve_chunk_paged": (eng._chunk_fn(8, 0), toks, state,
+                                      *pool, table),
+            "jit_serve_pack_paged": (eng._pack_fn(((0, 4), (0, 4))), toks,
+                                     (state, state), *pool, (table, table)),
+        }
+    else:
+        state = api.make_serve_state(cfg, 1, eng.max_len, eng.dtype,
+                                     ring_local=bool(cfg.attn_window))
+        programs = {
+            "jit_serve_decode": (eng._decode, toks[:, :1], state),
+            "jit_serve_chunk": (eng._chunk_fn(8, 0), toks, state),
+            "jit_serve_pack": (eng._pack_fn(((0, 4), (0, 4))), toks,
+                               (state, state)),
+            "jit_serve_prefill": (eng._prefill_fn(8), {"tokens": toks}),
+        }
+    for name, (fn, *args) in programs.items():
+        text = fn.lower(params, *args).as_text()
+        assert re.search(r"module @(\w+)", text).group(1) == name
