@@ -52,7 +52,8 @@ from repro.kernels.flash_attention.ref import fit_bkv
 NEG_INF = -2.0e30
 
 
-def paged_prefix(k_pages, v_pages, page_table, n_prefix_pages: int, start):
+def paged_prefix(k_pages, v_pages, page_table, n_prefix_pages: int, start,
+                 layer=None):
     """Dense view of a chunk's visible cache prefix from the paged pool.
 
     Gathers the first ``n_prefix_pages`` table entries (a static count —
@@ -62,10 +63,11 @@ def paged_prefix(k_pages, v_pages, page_table, n_prefix_pages: int, start):
     double duty: it hides the unwritten tail of a partially-filled last
     page AND a shared-prefix donor's own tokens past the shared length in
     a copy-on-write page (see serve/pool.py) — without it a prefix hit
-    would attend the donor's divergent continuation.
+    would attend the donor's divergent continuation. ``layer`` picks the
+    layer of stacked pages (see ``decode.paged_gather``).
     """
-    k = paged_gather(k_pages, page_table[:n_prefix_pages])
-    v = paged_gather(v_pages, page_table[:n_prefix_pages])
+    k = paged_gather(k_pages, page_table[:n_prefix_pages], layer)
+    v = paged_gather(v_pages, page_table[:n_prefix_pages], layer)
     span = k.shape[2]
     pos = jnp.arange(span, dtype=jnp.int32)
     kv_pos = jnp.where(pos < start, pos, -1)
@@ -76,14 +78,15 @@ def flash_prefill_chunk_paged_ref(
     q, k_chunk, v_chunk, k_pages, v_pages, page_table, *,
     q_pos, start, n_prefix_pages: int,
     window: Optional[int] = None, softcap: Optional[float] = None,
-    scale: Optional[float] = None, bkv: int = 512,
+    scale: Optional[float] = None, bkv: int = 512, layer=None,
 ):
     """``flash_prefill_chunk_ref`` over a paged cache prefix: gather the
-    prefix pages, concatenate the chunk's own keys (positions ``q_pos``),
-    and run the identical positioned online softmax."""
+    prefix pages (of ``layer`` when stacked), concatenate the chunk's own
+    keys (positions ``q_pos``), and run the identical positioned online
+    softmax."""
     if n_prefix_pages:
         kp, vp, pp = paged_prefix(
-            k_pages, v_pages, page_table, n_prefix_pages, start)
+            k_pages, v_pages, page_table, n_prefix_pages, start, layer)
         k_all = jnp.concatenate([kp, k_chunk.astype(kp.dtype)], axis=2)
         v_all = jnp.concatenate([vp, v_chunk.astype(vp.dtype)], axis=2)
         kv_pos = jnp.concatenate([pp, jnp.asarray(q_pos, jnp.int32)])

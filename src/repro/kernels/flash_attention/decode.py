@@ -251,35 +251,67 @@ def flash_decode_ref(
 # Paged-pool indirection (serve/pool.py). Pages hold token rows of one
 # request's cache behind a page table: ``pages`` [n_pages, Hkv, page, D],
 # ``page_table`` [n_pt] int32 mapping each logical page index of the request
-# to its physical page id. Gather/scatter here; the attention math delegates
-# to the refs above unchanged, so paged and dense lowerings cannot drift.
+# to its physical page id. A scanned layer stack keeps its layers' pages in
+# one stacked array ``[L, n_pages, Hkv, page, D]`` and passes ``layer``: the
+# read and the write then index that layer directly, so neither ever moves
+# more than the request's pages or rows. Reads and writes here; the
+# attention math delegates to the refs above unchanged, so paged and dense
+# lowerings cannot drift.
 # ---------------------------------------------------------------------------
 
-def paged_gather(pages, page_table):
-    """Dense [1, Hkv, n_pt*page, D] cache view of one request's pages.
+def paged_gather(pages, page_table, layer=None):
+    """Dense [1, Hkv, n_pt*page, D] cache view of one request's pages
+    (of layer ``layer`` when ``pages`` is stacked).
 
     Slots past the request's written length hold garbage (unallocated table
     entries point at physical page 0) — callers mask them positionally: the
     view is linear, so slot i is absolute position i and the usual
     ``kv_pos <= pos`` / ``kv_pos < start`` rules hide everything unwritten.
+
+    Each page is read by its own ``dynamic_slice``. An XLA gather here lets
+    the TPU compiler push the attention's KV split back through the gather
+    into the pages operand, copying the whole pool (and relayouting it) on
+    every call; a page-sized dynamic slice only ever reads its page.
     """
     n_pt = page_table.shape[0]
-    hkv, page, d = pages.shape[1:]
-    gathered = pages[page_table]                    # [n_pt, Hkv, page, D]
+    hkv, page, d = pages.shape[-3:]
+    lead = () if layer is None else (layer,)
+    size = (1,) * len(lead) + (1, hkv, page, d)
+    gathered = jnp.concatenate([
+        jax.lax.dynamic_slice(pages, lead + (page_table[j], 0, 0, 0),
+                              size).reshape(1, hkv, page, d)
+        for j in range(n_pt)])                      # [n_pt, Hkv, page, D]
     return gathered.transpose(1, 0, 2, 3).reshape(1, hkv, n_pt * page, d)
 
 
-def paged_write(pages, page_table, x, start):
-    """Scatter ``x`` [1, Hkv, c, D] into pages at positions
-    ``start .. start+c-1`` (start may be traced — decode's ``pos``).
-    Returns the updated pages array."""
-    c = x.shape[2]
-    page = pages.shape[2]
-    idx = start + jnp.arange(c, dtype=jnp.int32)
-    phys = page_table[idx // page]                  # [c] physical page ids
-    offs = idx % page
-    return pages.at[phys, :, offs, :].set(
-        x[0].transpose(1, 0, 2).astype(pages.dtype))
+def paged_write(pages, page_table, x, start, layer=None):
+    """Write ``x`` [1, Hkv, c, D] into pages (of layer ``layer`` when
+    ``pages`` is stacked) at positions ``start .. start+c-1``. Returns the
+    updated pages array.
+
+    Each write is a ``dynamic_update_slice`` of whole rows at
+    ``(layer, phys, :, off, :)``: one per page the span touches when
+    ``start`` is static (a chunk), one per row when it is traced (decode's
+    ``pos``). Only the ``c`` rows change, so a donated or loop-carried
+    array updates in place; a scatter indexing the page and row axes
+    around the head axis makes XLA relayout the whole array instead."""
+    rows = x[0].astype(pages.dtype)                 # [Hkv, c, D]
+    c = rows.shape[1]
+    page = pages.shape[-2]
+    if isinstance(start, jax.Array):
+        cuts = list(range(c + 1))
+    else:
+        start = int(start)
+        cuts = sorted({0, c} | {b - start for b in range(
+            (start // page + 1) * page, start + c, page)})
+    for a, b in zip(cuts[:-1], cuts[1:]):
+        pos = start + a
+        idx = (page_table[pos // page], 0, pos % page, 0)
+        upd = rows[None, :, a:b]                    # [1, Hkv, b-a, D]
+        if layer is not None:
+            idx, upd = (layer,) + idx, upd[None]
+        pages = jax.lax.dynamic_update_slice(pages, upd, idx)
+    return pages
 
 
 def flash_decode_paged_ref(

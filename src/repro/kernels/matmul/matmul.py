@@ -2,7 +2,13 @@
 
 The canonical MXU kernel: grid (m/bm, n/bn, k/bk) with the contraction
 dimension innermost ("arbitrary" semantics), f32 accumulator in VMEM scratch,
-cast on the final k step. The (bm, bk, bn) space is registered with the
+cast on the final k step. The right operand may be a stack of weights
+``[L, K, N]`` (a scanned layer stack's): the layer to multiply by is a
+scalar-prefetch index that the weight's block index map reads, so the
+kernel streams that layer's blocks straight from the stack in HBM. A
+sliced-out layer handed to a custom call is first copied whole by XLA
+(into HBM, or into VMEM where it fits), which costs a weight read of its
+own outside the kernel. The (bm, bk, bn) space is registered with the
 tile autotuner — the LM stack asks the TilingPolicy for block shapes instead
 of hard-coding them (the paper's methodology as infrastructure).
 """
@@ -16,7 +22,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _matmul_kernel(a_ref, b_ref, out_ref, acc_ref, *, n_k: int):
+def _matmul_kernel(layer_ref, a_ref, b_ref, out_ref, acc_ref, *, n_k: int):
+    del layer_ref                     # read by the weight's index map only
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -40,12 +47,19 @@ def matmul(
     tile: tuple[int, int, int] = (256, 512, 256),
     out_dtype=None,
     interpret: bool = False,
+    layer=None,
 ) -> jnp.ndarray:
-    """``a`` [M, K] @ ``b`` [K, N] -> [M, N] with block shapes (bm, bk, bn)."""
-    if a.ndim != 2 or b.ndim != 2 or a.shape[1] != b.shape[0]:
+    """``a`` [M, K] @ ``b`` [K, N] -> [M, N] with block shapes (bm, bk, bn).
+    With ``layer`` (an int32 scalar, traced or not), ``b`` is a stack
+    [L, K, N] and the product is ``a @ b[layer]``, read from the stack."""
+    if layer is None:
+        if b.ndim != 2:
+            raise ValueError(f"bad matmul weight {b.shape}: [K, N] expected")
+        b, layer = b[None], 0
+    if a.ndim != 2 or b.ndim != 3 or a.shape[1] != b.shape[1]:
         raise ValueError(f"bad matmul shapes {a.shape} @ {b.shape}")
     m, k = a.shape
-    _, n = b.shape
+    n = b.shape[2]
     out_dtype = out_dtype or a.dtype
     bm, bk, bn = (min(t, s) for t, s in zip(tile, (m, k, n)))
     if m % bm or k % bk or n % bn:
@@ -55,16 +69,20 @@ def matmul(
     kernel = functools.partial(_matmul_kernel, n_k=n_k)
     return pl.pallas_call(
         kernel,
-        grid=(m // bm, n // bn, n_k),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(m // bm, n // bn, n_k),
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda i, j, kk, l: (i, kk)),
+                pl.BlockSpec((pl.squeezed, bk, bn),
+                             lambda i, j, kk, l: (l[0], kk, j)),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk, l: (i, j)),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(a, b)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), a, b)

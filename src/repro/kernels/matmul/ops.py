@@ -26,8 +26,8 @@ from repro.kernels.matmul.ref import matmul_ref
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def mm(a, b, tile=(256, 512, 256), interpret: bool = False):
-    return matmul(a, b, tile=tile, interpret=interpret)
+def mm(a, b, tile=(256, 512, 256), interpret: bool = False, layer=None):
+    return matmul(a, b, tile=tile, interpret=interpret, layer=layer)
 
 
 def _constraints(problem: Mapping[str, int]) -> TileConstraints:

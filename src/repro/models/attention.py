@@ -280,14 +280,21 @@ def attn_prefill_chunk(
     softcap = cfg.attn_softcap or None
 
     if "k_pages" in cache:
-        # Pool-backed cache: the chunk attends over the ``cdiv(start,
+        # Pool-backed cache: the chunk writes its K/V through the table at
+        # the static start offset, then attends over the ``cdiv(start,
         # page)`` prefix pages its table maps (gathered to a positioned
-        # linear view; positions >= start masked — unwritten page tails and
-        # a shared-prefix donor's divergent tokens alike) plus itself, then
-        # writes its K/V through the table at the static start offset. The
-        # engine resolves copy-on-write BEFORE this runs (pool.prepare_span)
-        # so the written span's pages are exclusively owned.
-        page = cache["k_pages"].shape[2]
+        # linear view; positions >= start masked — unwritten page tails,
+        # the rows just written and a shared-prefix donor's divergent
+        # tokens alike) plus itself. Writing before reading, as decode
+        # does, lets XLA update the pool in place: a read of the old pages
+        # followed by a write makes it copy them. The engine resolves
+        # copy-on-write BEFORE this runs (pool.prepare_span) so the written
+        # span's pages are exclusively owned. A scanned stack hands the
+        # layer its stacked pages plus ``layer``.
+        layer = cache.get("layer")
+        kp = paged_write(cache["k_pages"], cache["table"], k, start, layer)
+        vp = paged_write(cache["v_pages"], cache["table"], v, start, layer)
+        page = kp.shape[-2]
         n_pp = cdiv(start, page)
         skv = n_pp * page + c
         if tile is not None:
@@ -301,11 +308,10 @@ def attn_prefill_chunk(
         else:
             bkv = 512
         out = flash_prefill_chunk_paged_ref(
-            q, k, v, cache["k_pages"], cache["v_pages"], cache["table"],
+            q, k, v, kp, vp, cache["table"],
             q_pos=positions[0], start=start, n_prefix_pages=n_pp,
-            window=window, softcap=softcap, scale=scale, bkv=bkv)
-        kp = paged_write(cache["k_pages"], cache["table"], k, start)
-        vp = paged_write(cache["v_pages"], cache["table"], v, start)
+            window=window, softcap=softcap, scale=scale, bkv=bkv,
+            layer=layer)
         y = _out_proj(p, cfg, out, x.dtype)
         return y, {"k_pages": kp, "v_pages": vp, "table": cache["table"],
                    "pos": jnp.asarray(start + c, jnp.int32)}
@@ -420,17 +426,27 @@ def attn_prefill_packed(
     if paged:
         # Pool-backed pack: by convention segment 0's cache carries the
         # SHARED page arrays (transformer.forward_packed merges them there);
-        # every segment carries its own page table. Prefix reads all see
-        # the pre-step pages (requests only share read-only prefix pages —
-        # the engine's copy-on-write pass guarantees written spans are
-        # exclusive), then the per-segment writes accumulate functionally.
+        # every segment carries its own page table. Every segment writes
+        # its rows first, then the prefix reads run (the chunked path's
+        # order, which keeps the pool update in place): a segment's prefix
+        # masks positions >= its start, and the engine's copy-on-write pass
+        # makes written spans exclusive, so no read sees another segment's
+        # write. A scanned stack passes its stacked pages with segment 0's
+        # ``layer``.
         k_pool, v_pool = caches[0]["k_pages"], caches[0]["v_pages"]
-        page = k_pool.shape[2]
+        layer = caches[0].get("layer")
+        page = k_pool.shape[-2]
 
     offs = [0]
     for _, ln in layout:
         offs.append(offs[-1] + ln)
     assert offs[-1] == s_packed, (offs, s_packed)
+    if paged:
+        for i, ((start, ln), cache) in enumerate(zip(layout, caches)):
+            k_pool = paged_write(k_pool, cache["table"],
+                                 k[:, :, offs[i]:offs[i] + ln], start, layer)
+            v_pool = paged_write(v_pool, cache["table"],
+                                 v[:, :, offs[i]:offs[i] + ln], start, layer)
 
     k_parts, v_parts, kvp_parts, kvs_parts = [], [], [], []
     for i, ((start, ln), cache) in enumerate(zip(layout, caches)):
@@ -443,7 +459,7 @@ def attn_prefill_packed(
             n_pp = cdiv(start, page)
             if n_pp:
                 kp_, vp_, pp_ = paged_prefix(
-                    k_pool, v_pool, cache["table"], n_pp, start)
+                    k_pool, v_pool, cache["table"], n_pp, start, layer)
                 k_parts += [kp_.astype(k.dtype), k_seg]
                 v_parts += [vp_.astype(v.dtype), v_seg]
                 kvp_parts += [pp_, seg_pos]
@@ -497,8 +513,6 @@ def attn_prefill_packed(
         v_seg = v[:, :, offs[i]:offs[i] + ln]
         seg_pos = positions[0, offs[i]:offs[i] + ln]
         if paged:
-            k_pool = paged_write(k_pool, cache["table"], k_seg, start)
-            v_pool = paged_write(v_pool, cache["table"], v_seg, start)
             new_caches.append({"table": cache["table"],
                                "pos": jnp.asarray(start + ln, jnp.int32)})
         elif ring:
@@ -622,7 +636,7 @@ def attn_decode(
     scale = cfg.query_scale or cfg.head_dim_ ** -0.5
 
     paged = "k_pages" in cache
-    max_len = (cache["table"].shape[0] * cache["k_pages"].shape[2]
+    max_len = (cache["table"].shape[0] * cache["k_pages"].shape[-2]
                if paged else cache["k"].shape[2])
     if (flags.DECODE_ATTN_SHARDED and ctx is not None and ctx.mesh is not None
             and not paged and "slot_pos" not in cache
@@ -639,11 +653,13 @@ def attn_decode(
         # pallas) is the same as for a resident linear cache, so the paged
         # lowering changes where bytes live, not the math. Unwritten tail
         # slots of the view hold stale pages' data; ``k_pos <= pos`` masks
-        # them exactly as it masks a linear cache's unwritten tail.
-        kp = paged_write(cache["k_pages"], cache["table"], k_new, pos)
-        vp = paged_write(cache["v_pages"], cache["table"], v_new, pos)
-        ck = paged_gather(kp, cache["table"])
-        cv = paged_gather(vp, cache["table"])
+        # them exactly as it masks a linear cache's unwritten tail. A
+        # scanned stack hands the layer its stacked pages plus ``layer``.
+        layer = cache.get("layer")
+        kp = paged_write(cache["k_pages"], cache["table"], k_new, pos, layer)
+        vp = paged_write(cache["v_pages"], cache["table"], v_new, pos, layer)
+        ck = paged_gather(kp, cache["table"], layer)
+        cv = paged_gather(vp, cache["table"], layer)
         slot_pos = None
         k_pos = jnp.arange(max_len)
         valid = k_pos <= pos

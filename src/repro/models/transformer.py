@@ -262,20 +262,32 @@ def _ff_blocks(tile, m: int, d: int, f: int):
     return (bm, bk, bn), (bm, bn, bk), round_up(m, bm)
 
 
-def _dense_ff(p, cfg: ArchConfig, x, tile=None, phase: str = "prefill"):
+def _ff_runs_kernel(tile, x, p) -> bool:
+    """Whether :func:`_dense_ff` runs ``x`` through the tiled Pallas matmul
+    kernel with FF weights ``p`` (a layer's, or a stack of them)."""
+    if tile is None or not flags.pallas_enabled():
+        return False
+    b, s, d = x.shape
+    return _ff_blocks(tile, b * s, d, p["w1"].shape[-1]) is not None
+
+
+def _dense_ff(p, cfg: ArchConfig, x, tile=None, phase: str = "prefill",
+              layer=None):
     """SwiGLU FF. ``tile`` is the plan-resolved matmul tile (bm, bk, bn);
     on TPU backends (and under interpret-mode Pallas) the three GEMMs run
     through the tiled Pallas matmul kernel with the blocks of
     :func:`_ff_blocks` (inference paths), elsewhere the einsum lowering is
     kept (Pallas TPU kernels cannot lower to host HLO). Like the attention
     sites, a call with a tile emits a tile event (``kernel="matmul"``), so
-    a tile the kernel could not run is counted as a fallback."""
+    a tile the kernel could not run is counted as a fallback. With
+    ``layer`` the weights are a scanned stack's and the kernel reads layer
+    ``layer`` from it (only where :func:`_ff_runs_kernel`)."""
     act = act_fn(cfg.act)
     b, s, d = x.shape
-    f = p["w1"].shape[1]
+    f = p["w1"].shape[-1]
     if tile is not None:
         blocks = _ff_blocks(tile, b * s, d, f)
-        pallas = flags.pallas_enabled() and blocks is not None
+        pallas = _ff_runs_kernel(tile, x, p)
         attn_mod.emit_tile_event(
             kernel="matmul", phase=phase,
             impl="pallas" if pallas else "reference", tile=tuple(tile),
@@ -289,11 +301,13 @@ def _dense_ff(p, cfg: ArchConfig, x, tile=None, phase: str = "prefill"):
             xf = jnp.pad(xf, ((0, mp - b * s), (0, 0)))
             interp = flags.pallas_interpret()
             h = act(mm(xf, p["w1"].astype(x.dtype), tile=up,
-                       interpret=interp))
+                       interpret=interp, layer=layer))
             h = h * mm(xf, p["w3"].astype(x.dtype), tile=up,
-                       interpret=interp)
-            y = mm(h, p["w2"].astype(x.dtype), tile=down, interpret=interp)
+                       interpret=interp, layer=layer)
+            y = mm(h, p["w2"].astype(x.dtype), tile=down, interpret=interp,
+                   layer=layer)
             return y[:b * s].reshape(b, s, d)
+    assert layer is None, "a stack of FF weights needs the Pallas kernel"
     h = act(jnp.einsum("bsd,df->bsf", x, p["w1"].astype(x.dtype)))
     h = h * jnp.einsum("bsd,df->bsf", x, p["w3"].astype(x.dtype))
     return jnp.einsum("bsf,fd->bsd", h, p["w2"].astype(x.dtype))
@@ -302,11 +316,12 @@ def _dense_ff(p, cfg: ArchConfig, x, tile=None, phase: str = "prefill"):
 def layer_forward(
     p, cfg: ArchConfig, spec: LayerSpec, x, positions, cache,
     ctx: Optional[DistContext], decode: bool = False, tiles=None,
-    chunk_start=None, pack_layout=None,
+    chunk_start=None, pack_layout=None, ff_layer=None,
 ):
     """Returns (x_out, new_cache, aux). With ``pack_layout`` (a packed
     multi-request step) ``cache`` is a tuple of per-request caches and the
-    returned new_cache matches."""
+    returned new_cache matches. With ``ff_layer``, ``p["ff"]`` holds the
+    scanned stack's FF weights and this layer is its ``ff_layer``-th."""
     aux = jnp.zeros((), jnp.float32)
     ff_tile = (tiles or {}).get("matmul")
     phase = "decode" if decode else "prefill"
@@ -318,14 +333,16 @@ def layer_forward(
         mix = _apply_norm(p, cfg, mix, "post1")
 
     if cfg.parallel_block and spec.ff is not None:
-        ff = _dense_ff(p["ff"], cfg, h, tile=ff_tile, phase=phase)
+        ff = _dense_ff(p["ff"], cfg, h, tile=ff_tile, phase=phase,
+                       layer=ff_layer)
         x = x + mix + ff
     else:
         x = x + mix
         if spec.ff is not None:
             h2 = _apply_norm(p, cfg, x, "norm2")
             if spec.ff == "dense":
-                ff = _dense_ff(p["ff"], cfg, h2, tile=ff_tile, phase=phase)
+                ff = _dense_ff(p["ff"], cfg, h2, tile=ff_tile, phase=phase,
+                               layer=ff_layer)
             else:
                 ff, aux = moe_mod.moe_forward(p["moe"], cfg, h2, ctx)
             if cfg.post_norms:
@@ -343,37 +360,70 @@ def layer_forward(
 def _scan_unit(
     unit_params, cfg: ArchConfig, unit: Tuple[LayerSpec, ...], x, positions,
     unit_caches, ctx, decode: bool, remat: bool, tiles=None, chunk_start=None,
-    pack_layout=None,
+    pack_layout=None, unit_pool=None, bind=None, unbind=None,
 ):
     """Scan a repeat unit (tuple of per-position stacked params) ``reps``
     times. unit_caches: matching list of stacked caches (or None); in a
     packed step each element is a TUPLE of per-request stacked caches —
-    scan slices every leaf's rep axis, tuples included."""
+    scan slices every leaf's rep axis, tuples included.
+
+    ``unit_pool`` (one stacked paged-pool leaf ``[reps, n_pages, ...]`` per
+    unit position, None for non-attention positions) rides the scan CARRY,
+    not ``xs``/``ys``: iteration ``i`` hands each layer the whole stacked
+    leaf and its index (``bind(cache, leaf, i)`` merges them into the
+    layer's cache, ``unbind(new_cache)`` splits ``(state, leaf)`` back out),
+    so a layer reads only its request's pages and writes only its new rows,
+    in place, instead of slicing out and restacking its whole layer of the
+    pool.
+
+    Where the FF runs the tiled matmul kernel, its stacked weights stay out
+    of ``xs`` as well: the kernel reads iteration ``i``'s layer from the
+    stack, so XLA never copies a layer's weights out of it before the
+    kernel. Returns ``(x, new_caches, aux, new_unit_pool)``."""
+    if unit_pool is None:
+        unit_pool = [None] * len(unit)
+    ff_tile = (tiles or {}).get("matmul")
+    ff_stacks = [lp["ff"] if spec.ff == "dense"
+                 and _ff_runs_kernel(ff_tile, x, lp["ff"]) else None
+                 for spec, lp in zip(unit, unit_params)]
+    unit_params = [lp if ff is None else
+                   {k: v for k, v in lp.items() if k != "ff"}
+                   for lp, ff in zip(unit_params, ff_stacks)]
 
     def body(carry, xs):
-        xc, aux_sum = carry
-        lps, lcs = xs
-        ncs = []
-        for spec, lp, lc in zip(unit, lps, lcs):
+        xc, aux_sum, pls = carry
+        lps, lcs, layer = xs
+        ncs, npls = [], []
+        for spec, lp, lc, pl, ff in zip(unit, lps, lcs, pls, ff_stacks):
+            if pl is not None:
+                lc = bind(lc, pl, layer)
+            if ff is not None:
+                lp = {**lp, "ff": ff}
             xc, nc, aux = layer_forward(lp, cfg, spec, xc, positions, lc,
                                         ctx, decode, tiles=tiles,
                                         chunk_start=chunk_start,
-                                        pack_layout=pack_layout)
+                                        pack_layout=pack_layout,
+                                        ff_layer=None if ff is None else layer)
+            if pl is not None:
+                nc, pl = unbind(nc)
             aux_sum = aux_sum + aux
             ncs.append(nc)
-        return (xc, aux_sum), ncs
+            npls.append(pl)
+        return (xc, aux_sum, tuple(npls)), ncs
 
     fn = body
     if remat:
         fn = jax.checkpoint(body, policy=flags.remat_policy())
     if unit_caches is None:
         unit_caches = [None] * len(unit)
-    (x, aux), new_caches = jax.lax.scan(
-        fn, (x, jnp.zeros((), jnp.float32)),
-        (tuple(unit_params), tuple(unit_caches)),
+    reps = jax.tree.leaves(unit_params)[0].shape[0]
+    layers = jnp.arange(reps, dtype=jnp.int32)
+    (x, aux, new_pool), new_caches = jax.lax.scan(
+        fn, (x, jnp.zeros((), jnp.float32), tuple(unit_pool)),
+        (tuple(unit_params), tuple(unit_caches), layers),
         unroll=flags.scan_unroll(),
     )
-    return x, list(new_caches), aux
+    return x, list(new_caches), aux, list(new_pool)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -466,13 +516,18 @@ def make_paged_pool(
     return pool
 
 
-def _merge_pool_leaf(cache, pool_leaf, table):
+def _merge_pool_leaf(cache, pool_leaf, table, layer=None):
     """Hand a layer its pool pages + page table by merging them into its
     cache dict — the attention paths dispatch on ``k_pages``/``table`` keys,
-    so scan/remat plumbing never changes shape."""
+    so scan/remat plumbing never changes shape. In a scanned segment the
+    pages are the whole stacked leaf and ``layer`` says which layer is
+    this one's."""
     if pool_leaf is None:
         return cache
-    return {**cache, **pool_leaf, "table": table}
+    merged = {**cache, **pool_leaf, "table": table}
+    if layer is not None:
+        merged["layer"] = layer
+    return merged
 
 
 def _split_pool_leaf(new_cache):
@@ -575,22 +630,15 @@ def forward(
                     nps.append(pl)
                 ncs.append(nc)
         else:
-            _, unit, reps = seg
-            if pg is not None:
-                tbl = jnp.broadcast_to(
-                    page_table[None], (reps,) + page_table.shape)
-                gc = [_merge_pool_leaf(c, pl, tbl)
-                      for c, pl in zip(gc, pg)]
-            x, ncs, aux = _scan_unit(
+            _, unit, _ = seg
+            x, ncs, aux, nps = _scan_unit(
                 gp, cfg, unit, x, positions, gc, ctx, decode,
                 remat=remat and not decode, tiles=tiles,
-                chunk_start=chunk_start,
+                chunk_start=chunk_start, unit_pool=pg,
+                bind=lambda c, pl, i: _merge_pool_leaf(c, pl, page_table, i),
+                unbind=_split_pool_leaf,
             )
             aux_total = aux_total + aux
-            if pg is not None:
-                split = [_split_pool_leaf(nc) for nc in ncs]
-                ncs = [st for st, _ in split]
-                nps = [pl for _, pl in split]
         if new_caches is not None:
             new_caches.append(ncs)
         if new_pool is not None:
@@ -654,19 +702,16 @@ def forward_packed(
                              or len(page_tables) != n_req):
         raise ValueError("pool-backed pack needs one page table per segment")
 
-    def _merge_packed(cs, pool_leaf, reps=None):
+    def _merge_packed(cs, pool_leaf, layer=None):
         # Per-request merged caches: every segment gets its own table,
-        # segment 0 additionally carries the shared page arrays.
+        # segment 0 additionally carries the shared page arrays (and, in a
+        # scanned segment, the layer index).
         if pool is None or pool_leaf is None:
             return cs
-        merged = []
-        for r, c in enumerate(cs):
-            tbl = page_tables[r]
-            if reps is not None:
-                tbl = jnp.broadcast_to(tbl[None], (reps,) + tbl.shape)
-            merged.append(_merge_pool_leaf(
-                c, pool_leaf if r == 0 else {}, tbl))
-        return tuple(merged)
+        return tuple(
+            _merge_pool_leaf(c, pool_leaf, page_tables[r], layer) if r == 0
+            else _merge_pool_leaf(c, {}, page_tables[r])
+            for r, c in enumerate(cs))
 
     def _split_packed(ncs):
         st0, pl = _split_pool_leaf(ncs[0])
@@ -708,24 +753,14 @@ def forward_packed(
             for r in range(n_req):
                 new_states[r].append([nc[r] for nc in ncs])
         else:
-            _, unit, reps = seg
+            _, unit, _ = seg
             gc = [tuple(st[gi][ui] for st in states)
                   for ui in range(len(unit))]
-            if pg is not None:
-                gc = [_merge_packed(cs, pg[ui], reps=reps)
-                      for ui, cs in enumerate(gc)]
-            x, ncs, _ = _scan_unit(
+            x, ncs, _, nps = _scan_unit(
                 gp, cfg, unit, x, positions, gc, ctx, False, remat=False,
-                tiles=tiles, pack_layout=layout,
+                tiles=tiles, pack_layout=layout, unit_pool=pg,
+                bind=_merge_packed, unbind=_split_packed,
             )
-            if pg is not None:
-                nps = []
-                stripped = []
-                for nc in ncs:
-                    nc, pl = _split_packed(nc)
-                    stripped.append(nc)
-                    nps.append(pl)
-                ncs = stripped
             for r in range(n_req):
                 new_states[r].append([nc[r] for nc in ncs])
         if new_pool is not None:

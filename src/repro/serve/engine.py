@@ -512,7 +512,9 @@ class ServeEngine:
                                          tiles=self.tiles or None)
 
         self._decode = jax.jit(serve_decode)
-        self._decode_paged = jax.jit(serve_decode_paged)
+        # The pool (argument 3) is donated: the program updates it in place
+        # and the caller stores the returned arrays back into the pool.
+        self._decode_paged = jax.jit(serve_decode_paged, donate_argnums=3)
 
     def _prefill_fn(self, length: int):
         """The jitted prefill program for one admitted prompt length.
@@ -717,7 +719,9 @@ class ServeEngine:
             return api.prefill_chunk(p, cfg, toks, st, start,
                                      tiles=tiles or None)
 
-        fn = jax.jit(serve_chunk_paged if self.paged else serve_chunk)
+        # Like decode, the paged chunk program donates the pool.
+        fn = (jax.jit(serve_chunk_paged, donate_argnums=3) if self.paged
+              else jax.jit(serve_chunk))
         self._chunk_fns[key] = fn
         return fn
 
@@ -796,6 +800,10 @@ class ServeEngine:
             return api.prefill_packed(p, cfg, toks, sts, layout,
                                       tiles=tiles or None)
 
+        # Unlike decode and chunk, the paged packed program does not donate
+        # the pool: a warm-up may run it on the engine's live pool and drop
+        # the result, which must leave that pool intact. So the program
+        # returns a fresh copy of the pool.
         fn = jax.jit(serve_pack_paged if self.paged else serve_pack)
         self._pack_fns[layout] = fn
         return fn
@@ -857,6 +865,11 @@ class ServeEngine:
                     self.pool.prepare_span(job.req.rid, start, take)
                 tables = tuple(self.pool.device_table(job.req.rid)
                                for job in jobs)
+                # The packed program returns a new pool (it does not donate
+                # the old one). Wait for the pool's last writer first, so
+                # that one copy at most is in flight: back-to-back packs
+                # otherwise hold three pools at once.
+                jax.block_until_ready(self.pool.arrays)
                 args = (self.params, toks, states, self.pool.arrays, tables)
                 if events is None:
                     captured: List[Dict[str, Any]] = []

@@ -42,9 +42,16 @@ All device-side state lives in ``self.arrays`` (a pytree mirroring the
 model's cache segment structure; see ``transformer.make_paged_pool``) and
 is threaded *functionally* through the jitted decode/prefill programs: the
 engine passes ``pool.arrays`` in, the program returns the updated arrays,
-and the engine stores them back. Host-side bookkeeping (tables, refcounts,
-free list, prefix registry) is plain Python — nanoseconds per request, no
-jax on the admission path.
+and the engine stores them back. Inside a program the pool is updated in
+place: a scanned layer stack carries its stacked page arrays through the
+scan, and each layer writes only its new rows and reads only its request's
+pages. The decode and chunk programs donate ``pool.arrays``, so their
+output reuses the input buffers and the arrays passed in are deleted: hold
+no reference to them across a launch. The packed program does not donate
+(a warm-up may run it on the live pool and drop the result), so it returns
+a fresh copy of the pool. Host-side bookkeeping (tables, refcounts, free
+list, prefix registry) is plain Python — nanoseconds per request, no jax
+on the admission path.
 """
 from __future__ import annotations
 

@@ -26,7 +26,11 @@ service modes and asserts:
   (a hot layout survives cap-many cold layouts), freed capacity is re-used
   in the same step it frees (second admission pass), and ring-cache
   wraparound at exact ``cache_len`` boundaries matches whole-prompt
-  prefill position by position.
+  prefill position by position;
+* **in-place pool** — the compiled decode and chunk programs alias the
+  donated pool and keep no pool-sized temporaries, the packed program
+  aliases nothing, and donation never costs a later request its tokens
+  or a warm-up its pool.
 
 Run on the reference lowerings by default; the CI ``paged-conformance``
 job adds an interpret-mode Pallas leg (REPRO_PALLAS_INTERPRET=1) so the
@@ -39,8 +43,8 @@ import jax
 import numpy as np
 import pytest
 
-sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]
-                       / "benchmarks"))
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT / "benchmarks"))
 import traces as trace_lib  # noqa: E402  (benchmarks/traces.py)
 
 from repro import configs  # noqa: E402
@@ -495,3 +499,92 @@ def test_ring_cache_exact_boundary_parity():
                                        err_msg=f"decode after cuts={cuts}")
             tok_r = jnp.argmax(dr[:, :cfg.vocab_size], -1)[:, None]
             tok_c = jnp.argmax(dc[:, :cfg.vocab_size], -1)[:, None]
+
+
+# ---------------------------------------------------------------------------
+# In-place pool: aliasing of the compiled programs, and donation safety
+# ---------------------------------------------------------------------------
+
+def _paged_programs(cfg, params, eng):
+    """The engine's three pool-backed programs with arguments of the types
+    the engine gives them: decode, a chunk that reads a prefix page, and a
+    two-segment pack."""
+    import jax.numpy as jnp
+
+    state = api.make_paged_state(cfg, eng.dtype)
+    table = jnp.zeros((eng.pool.n_pt,), jnp.int32)
+    toks = jnp.zeros((1, PAGE), jnp.int32)
+    arrays = eng.pool.arrays
+    return {
+        "decode": (eng._decode_paged, params, toks[:, :1], state, arrays,
+                   table),
+        "chunk": (eng._chunk_fn(max(EDGES), PAGE), params, toks, state,
+                  arrays, table),
+        "pack": (eng._pack_fn(((PAGE, PAGE // 2), (0, PAGE // 2))), params,
+                 toks, (state, state), arrays, (table, table)),
+    }
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "pack"])
+def test_paged_programs_update_pool_in_place(smoke_model, program):
+    """Decode and chunk donate the pool and update it in place: the
+    compiled program aliases the whole pool and its temporaries stay far
+    below it (each layer reads only the request's pages and writes only its
+    new rows). The packed program does not donate (a warm-up may run it on
+    the live pool and drop the result): it aliases nothing, and still keeps
+    no pool-sized temporary."""
+    cfg, params = smoke_model
+    eng = _engine(cfg, params, "packed", paged=True, pool_pages=320)
+    assert eng.pool.n_pages >= 4 * eng.pool.n_pt
+    pool_bytes = sum(a.nbytes for a in jax.tree.leaves(eng.pool.arrays))
+    fn, *args = _paged_programs(cfg, params, eng)[program]
+    mem = fn.lower(*args).compile().memory_analysis()
+    if program == "pack":
+        assert mem.alias_size_in_bytes == 0
+        assert mem.temp_size_in_bytes < pool_bytes
+    else:
+        assert mem.alias_size_in_bytes >= pool_bytes
+        assert mem.temp_size_in_bytes < pool_bytes / 4
+
+
+def test_donated_pool_serves_back_to_back_streams(smoke_model):
+    """Every decode and chunk launch consumes the pool it was given. An
+    engine that served one stream serves a second exactly as a fresh
+    engine does, and the arrays it held before serving are gone (donated,
+    not copied). Chunked mode, so that no (undonating) pack runs."""
+    cfg, params = smoke_model
+    first = trace_lib.make_trace("bimodal", seed=1, vocab=cfg.vocab_size,
+                                 edges=EDGES, n=4)
+    second = trace_lib.make_trace("all_short", seed=2, vocab=cfg.vocab_size,
+                                  edges=EDGES, n=4)
+    eng = _engine(cfg, params, "chunked", paged=True)
+    before = jax.tree.leaves(eng.pool.arrays)
+    _serve(eng, first)
+    assert all(a.is_deleted() for a in before)
+    done = len(eng._finished)
+    _serve(eng, second)
+    again = [tuple(r.out_tokens) for r in eng._finished[done:]]
+    fresh = _engine(cfg, params, "chunked", paged=True)
+    _serve(fresh, second)
+    assert again == [tuple(r.out_tokens) for r in fresh._finished]
+    eng.pool.check_balanced()
+
+
+def test_warm_packs_keeps_the_pool(smoke_model):
+    """``chipbench.harness.warm_packs`` runs the packed program on the
+    engine's live pool and drops what it returns. The packed program does
+    not donate, so the pool survives and the engine then serves as a fresh
+    one does."""
+    if str(ROOT) not in sys.path:
+        sys.path.insert(0, str(ROOT))
+    from chipbench import harness
+
+    cfg, params = smoke_model
+    trace = trace_lib.make_trace("bimodal", seed=3, vocab=cfg.vocab_size,
+                                 edges=EDGES, n=4)
+    eng = _engine(cfg, params, "packed", paged=True)
+    harness.warm_packs(eng, [((0, 8), (0, 8)), ((PAGE, 8), (0, 4))])
+    assert not any(a.is_deleted() for a in jax.tree.leaves(eng.pool.arrays))
+    warmed, _ = _serve(eng, trace)
+    fresh, _ = _serve(_engine(cfg, params, "packed", paged=True), trace)
+    assert warmed == fresh

@@ -12,6 +12,7 @@ only one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -153,3 +154,76 @@ def test_decode_cell_certifies_only_chip_legal_splits(skv):
         (bkv,) = res.tile.dims
         assert other["skv"] % bkv == 0
         assert bkv % 128 == 0 or bkv == other["skv"]
+
+
+@pytest.mark.parametrize("arch,program,start", [
+    ("h2o-danube-1.8b", "decode", None), ("h2o-danube-1.8b", "chunk", 512),
+    ("h2o-danube-1.8b", "chunk", 2048), ("qwen2-1.5b", "decode", None),
+    ("qwen2-1.5b", "chunk", 512),
+])
+def test_paged_program_updates_pool_in_place_for_v5e(arch, program, start,
+                                                     one_chip, monkeypatch):
+    """The paged decode and chunk programs of h2o-danube-1.8b (head_dim 80)
+    and qwen2-1.5b (head_dim 128), as the engine builds them at published
+    widths in bf16 (4096 positions, 24 pages of 2048, a chunk inside a page
+    and one at a page boundary), compiled for a v5e: they alias the donated
+    pool and keep no pool-sized temporary, and the FF kernels read each
+    layer's weights from the stack (no op makes a layer's [d, f] weight).
+    The TPU compiler can turn a read of the request's pages into copies of
+    the whole pool (an XLA gather did) where the CPU compiler shows
+    nothing.
+
+    Known and bounded here: qwen2-1.5b's chunk program relayouts its K
+    pool at entry and exit (the loop carries K with rows minor, to match
+    the chunk's projected keys), one K pool of temporaries; before the
+    pool was updated in place it held the whole pool."""
+    from repro.models import api, flags
+    from repro.serve import ServeEngine
+
+    cfg = configs.get_arch(arch)
+    monkeypatch.setattr(flags, "pallas_enabled", lambda: True)
+    monkeypatch.setattr(flags, "pallas_interpret", lambda: False)
+    make_pool = api.make_paged_pool
+    monkeypatch.setattr(api, "make_paged_pool",
+                        lambda *a: jax.eval_shape(lambda: make_pool(*a)))
+    kernels.register_all()
+    cells = [(k, p, "bfloat16", TPU_V5E) for kind, batch, n in (
+        ("decode", SLOTS, 4096), ("chunked_prefill", 1, BUCKET))
+        for k, p in kernel_problems(cfg, batch, n, kind).items()]
+
+    def sds(tree):
+        return jax.tree.map(
+            lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+
+    params = sds(jax.eval_shape(lambda: api.init_params(
+        cfg, jax.random.PRNGKey(0), jnp.bfloat16)))
+    eng = ServeEngine(cfg, params, max_len=4096, slots=SLOTS,
+                      dtype=jnp.bfloat16, plans=compile_plan(cells),
+                      hardware=TPU_V5E, paged=True, pool_pages=24,
+                      page_size=2048)
+    pool = sds(eng.pool.arrays)
+
+    def nbytes(leaves):
+        return sum(a.size * a.dtype.itemsize for a in leaves)
+
+    pool_bytes = nbytes(jax.tree.leaves(pool))
+    k_bytes = nbytes(jax.tree.leaves(jax.tree.map(
+        lambda leaf: leaf["k_pages"], pool,
+        is_leaf=lambda leaf: isinstance(leaf, dict) and "k_pages" in leaf)))
+    state = sds(jax.eval_shape(lambda: api.make_paged_state(cfg,
+                                                            eng.dtype)))
+    table = _sds((eng.pool.n_pt,), jnp.int32, one_chip)
+    if program == "decode":
+        fn, toks = eng._decode_paged, _sds((1, 1), jnp.int32, one_chip)
+    else:
+        fn = eng._chunk_fn(4096, start)
+        toks = _sds((1, BUCKET), jnp.int32, one_chip)
+    compiled = fn.lower(params, toks, state, pool, table).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    if arch == "qwen2-1.5b" and program == "chunk":
+        assert mem.temp_size_in_bytes < k_bytes + pool_bytes / 16
+    else:
+        assert mem.temp_size_in_bytes < pool_bytes / 4
+    d, f = cfg.d_model, cfg.d_ff
+    assert not re.search(rf"\[({d},{f}|{f},{d})\]", compiled.as_text())
