@@ -12,8 +12,9 @@ widest logit gap of the served tokens (the program's reading) and, for
 each ``--modes`` entry, the widest gap of the token the reference computed
 in that lower precision puts first (the control's reading).
 ``--program-heads`` adds the served tokens' gap against a reference whose
-query heads read the key/value heads the way the program groups them
-after padding its head count, as a witness of where a disagreement lies.
+query heads are grouped by ``padded_heads // padded_kv_heads``, as a
+program that pads its query heads and groups them naively would read
+them (``program_kv_map``): a witness of where a disagreement lies.
 Not part of a benchmark run.
 """
 from __future__ import annotations
@@ -31,9 +32,12 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 
 def program_kv_map(cfg) -> tuple:
-    """Which key/value head each real query head reads in the program:
-    query heads are padded to ``cfg.padded_heads`` and grouped by
-    ``padded_heads // padded_kv_heads``."""
+    """Which key/value head each real query head reads when query heads
+    are grouped by ``cfg.padded_heads // cfg.padded_kv_heads``. With no
+    padding that is the published head; with 12 heads padded to 16 over 2
+    key/value heads it is ``h // 8`` where the published model reads
+    ``h // 6``, so heads 6 and 7 read the wrong one. Whether the program
+    groups its heads so is for the comparison to show."""
     group = cfg.padded_heads // cfg.padded_kv_heads
     return tuple(h // group for h in range(cfg.n_heads))
 

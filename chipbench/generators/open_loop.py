@@ -1,12 +1,15 @@
 """Open-loop traffic: requests due on a fixed schedule, whatever the server does.
 
 A mix file (``traffic/<mix>.json``) gives the arrival process and the
-prompt and output length distributions. The SET of inter-arrival gaps,
-prompt lengths and output lengths is drawn once from the mix's own
-``base_seed``, so every run seed offers the same work; the run seed only
-permutes their order and draws the prompts' token ids. The gaps are
-rescaled so that ``n = round(rate * seconds)`` requests fall due in
-``[0, seconds)`` at exactly the mean rate.
+prompt and output length distributions. One sequence of requests (each
+with its prompt length, output length and the gap to the next) is drawn
+from the mix's own ``base_seed``, so every run seed offers the same work;
+the run seed rotates that sequence, starting it at a request of its own,
+and draws the prompts' token ids. A rotation keeps every burst of the
+sequence together, so seeds differ in where the window starts and not in
+how the requests cluster. The gaps are rescaled so that
+``n = round(rate * seconds)`` requests fall due in ``[0, seconds)`` at
+exactly the mean rate.
 
 Distributions (each with optional ``min``/``max`` clipping for lengths):
 
@@ -71,11 +74,11 @@ def schedule(mix: dict, rate: float, seconds: float, seed: int,
     prompts = _lengths(mix["prompt_tokens"], n, base)
     outputs = _lengths(mix["output_tokens"], n, base)
     gaps = gaps * (n / rate) / gaps.sum()
-    # The run seed permutes the fixed sets and draws the token ids.
+    # The run seed rotates the fixed sequence and draws the token ids.
     rng = np.random.default_rng(np.random.SeedSequence([seed, 0x5EED]))
-    gaps = gaps[rng.permutation(n)]
-    prompts = prompts[rng.permutation(n)]
-    outputs = outputs[rng.permutation(n)]
+    start = int(rng.integers(n))
+    gaps, prompts, outputs = (np.roll(x, -start)
+                              for x in (gaps, prompts, outputs))
     due = np.concatenate([[0.0], np.cumsum(gaps)[:-1]])
     return [Arrival(float(due[i]),
                     rng.integers(FIRST_TOKEN_ID, vocab, int(prompts[i]),

@@ -1,11 +1,13 @@
 """The plain reference against the program, on the CPU at small sizes.
 
 Where the program's query heads need no padding (a multiple of 16), its
-prefill logits agree with the reference's. Where they do (qwen2-1.5b's 12
-heads are padded to 16), the program reads key/value head ``h // 8``
-instead of the published ``h // 6``: its logits then disagree with the
-reference and agree with a reference that groups heads as the program
-does. That is the fault that keeps qwen2-1.5b out of the benchmark.
+prefill logits agree with the reference's. The reference groups query
+heads as published (head ``h`` reads key/value head ``h // (heads /
+kv_heads)``), and it tells that grouping apart from the one a naive
+padding of 12 query heads to 16 gives (``h // 8`` in place of ``h //
+6``): a program that grouped its heads so could not pass a cell's check.
+These tests read the benchmark's own code only, so they hold whichever
+way the program lays out its padded heads.
 """
 import sys
 from pathlib import Path
@@ -18,7 +20,7 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-from chipbench import control, model, reference  # noqa: E402
+from chipbench import model, reference  # noqa: E402
 
 
 def _conf(arch, heads, kv, bias, tied, window):
@@ -40,14 +42,23 @@ def _program_logits(cfg, params, tokens):
     return np.asarray(full[0, :, :cfg.vocab_size], np.float32)
 
 
-def _compare(conf, kv_map=None):
+def _inputs(conf):
+    """The arch config, the weights and 40 prompt tokens a test reads."""
     cfg = model.arch_config(conf)
     params = model.make_params(cfg, conf, seed=3)
     tokens = np.random.default_rng(0).integers(2, 128, 40).astype(np.int32)
-    prog = _program_logits(cfg, params, tokens)
-    ref = reference.logits(params, conf, tokens, np.arange(40),
-                           kv_map=kv_map)
-    return float(np.max(np.abs(prog - ref)) / np.max(np.abs(ref))), cfg
+    return cfg, params, tokens
+
+
+def _distance(logits, ref):
+    """Widest gap from ``ref``, relative to ``ref``'s largest logit."""
+    return float(np.max(np.abs(logits - ref)) / np.max(np.abs(ref)))
+
+
+def _compare(conf):
+    cfg, params, tokens = _inputs(conf)
+    ref = reference.logits(params, conf, tokens, np.arange(40))
+    return _distance(_program_logits(cfg, params, tokens), ref), cfg
 
 
 @pytest.mark.parametrize("arch,heads,kv,bias,tied,window", [
@@ -61,10 +72,24 @@ def test_program_agrees_with_reference_without_head_padding(
     assert err < 1e-4
 
 
-def test_padded_heads_read_the_wrong_kv_head():
+def _reference_gap(conf, kv_map):
+    """Relative distance of the reference's logits under ``kv_map`` from
+    its logits with the default grouping."""
+    _, params, tokens = _inputs(conf)
+    want = np.arange(40)
+    return _distance(
+        reference.logits(params, conf, tokens, want, kv_map=kv_map),
+        reference.logits(params, conf, tokens, want))
+
+
+def test_reference_groups_heads_as_published():
     conf = _conf("qwen2-1.5b", 12, 2, True, True, 0)
-    err, cfg = _compare(conf)
-    assert cfg.padded_heads == 16
-    assert err > 1e-2
-    witness, _ = _compare(conf, kv_map=control.program_kv_map(cfg))
-    assert witness < 1e-4
+    assert _reference_gap(conf, tuple(h // 6 for h in range(12))) < 1e-6
+
+
+def test_reference_tells_padded_grouping_apart():
+    """12 query heads padded to 16 and grouped 8 to a key/value head make
+    heads 6 and 7 read key/value head 0, where the published model has
+    them read head 1: the reference reads far off under that grouping."""
+    conf = _conf("qwen2-1.5b", 12, 2, True, True, 0)
+    assert _reference_gap(conf, tuple(h // 8 for h in range(12))) > 1e-2

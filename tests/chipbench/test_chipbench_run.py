@@ -32,6 +32,12 @@ TINY = {
               "pad_id": 0},
     "reduced": [], "assumed": [],
 }
+# qwen2's shape: bias on q, k and v, a tied head, no window. 16 query heads
+# of 16, so no head is padded and the program groups them as published.
+QWEN2 = dict(TINY, arch="qwen2-1.5b", model_type="qwen2",
+             num_attention_heads=16, num_key_value_heads=2,
+             sliding_window=0, attention_bias=True, tie_word_embeddings=True)
+SHAPES = {"danube": TINY, "qwen2": QWEN2}
 MIX = {"generator": "open_loop", "base_seed": 7,
        "arrivals": {"kind": "gamma", "cv": 2.0},
        "prompt_tokens": {"kind": "uniform", "min": 8, "max": 60},
@@ -41,7 +47,10 @@ LIMIT = 0.1
 
 
 @pytest.fixture
-def tiny_cell(tmp_path, monkeypatch):
+def tiny_cell(request, tmp_path, monkeypatch):
+    """A one-cell benchmark at a tiny size, of the shape the test names
+    (``danube`` unless parametrised)."""
+    shape = SHAPES[getattr(request, "param", "danube")]
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     bench["configs"] = [{"name": "tiny", "source": "test",
                          "file": "chipbench/configs/tiny.json",
@@ -50,7 +59,7 @@ def tiny_cell(tmp_path, monkeypatch):
                            "traffic": "mix", "chips": 1, "why": "test"}]
     for m in bench["end_to_end"] + bench["per_layer"]:
         m.pop("workloads", None)
-    files = {"BENCHMARK.json": bench, "chipbench/configs/tiny.json": TINY,
+    files = {"BENCHMARK.json": bench, "chipbench/configs/tiny.json": shape,
              "chipbench/traffic/mix.json": MIX,
              "chipbench/cells/tiny.mix.json": {
                  "rate_per_s": 12.0,
@@ -78,6 +87,7 @@ def _run(cell, capsys, trace=0):
     return json.loads(out.out.strip().splitlines()[-1]), out.err
 
 
+@pytest.mark.parametrize("tiny_cell", sorted(SHAPES), indirect=True)
 def test_run_prints_a_result_line(tiny_cell, capsys):
     result, err = _run(tiny_cell, capsys)
     assert set(result) >= {"correct", "attempted", "failed", "metrics",
@@ -108,6 +118,7 @@ def test_altered_token_is_not_correct(tiny_cell, capsys, monkeypatch):
     assert result["checks"]["max_logit_gap"]["value"] > LIMIT
 
 
+@pytest.mark.parametrize("tiny_cell", sorted(SHAPES), indirect=True)
 def test_traced_run_reports_per_layer_metrics(tiny_cell, capsys):
     result, _ = _run(tiny_cell, capsys, trace=1)
     # On the CPU the trace has no TPU plane: the host-clock and counter

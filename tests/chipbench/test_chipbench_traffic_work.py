@@ -1,7 +1,8 @@
 """The benchmark's yardstick on the CPU: traffic and work counts.
 
 Each generator is a pure function of its seed and stays inside its mix's
-ranges; every seed offers the same set of sizes and arrivals. The work
+ranges; every seed offers the same sequence of sizes and arrivals,
+rotated. The work
 counts match numbers worked by hand for qwen2-1.5b and h2o-danube-1.8b.
 """
 import json
@@ -38,12 +39,15 @@ def test_generator_is_a_pure_function_of_its_seed(mix_name):
     for x, y in zip(a, b):
         assert x.due_s == y.due_s and x.max_new_tokens == y.max_new_tokens
         np.testing.assert_array_equal(x.prompt, y.prompt)
-    # Another seed: the same sizes and gaps, in another order.
-    assert sorted(len(x.prompt) for x in a) == sorted(len(x.prompt)
-                                                       for x in c)
-    assert sorted(x.max_new_tokens for x in a) == sorted(
-        x.max_new_tokens for x in c)
-    assert [x.due_s for x in a] != [x.due_s for x in c]
+    # Another seed: the same sizes and gaps, rotated to start elsewhere.
+    def requests(arrivals):
+        gaps = np.diff([x.due_s for x in arrivals] + [30.0])
+        return [(len(x.prompt), x.max_new_tokens, round(g, 9))
+                for x, g in zip(arrivals, gaps)]
+
+    ra, rc = requests(a), requests(c)
+    assert ra != rc
+    assert any(ra == rc[k:] + rc[:k] for k in range(len(rc)))
     due = [x.due_s for x in a]
     assert due[0] == 0.0 and due == sorted(due) and due[-1] < 30.0
     lo, hi = mix["prompt_tokens"]["min"], mix["prompt_tokens"]["max"]
